@@ -527,7 +527,10 @@ def eval_ranking_epoch_scan(
         pu = _user_vector(params, u, h)
         r_u = effective_ranks(pu, t_p)
         pm = pu.astype(jnp.float32) * rank_mask(r_u, k)
-        scores = jnp.dot(pm, qm.T, preferred_element_type=jnp.float32)
+        scores = jnp.dot(
+            pm, qm.T, preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        )
         if item_bias is not None:
             scores = scores + item_bias[None, :]
         _, idx = jax.lax.top_k(scores, topk)
@@ -591,13 +594,11 @@ def init_error_feedback_state(
       dp)`` — each data rank's untransmitted part of the q-delta
       all-gather, keyed by item row.
     """
-    from repro.distributed import mesh_compat
-
-    mesh = mesh_compat.resolve_mesh(mesh)
-    if mesh is None:
+    mesh = mesh if mesh is not None else jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         raise ValueError(
             "init_error_feedback_state needs a mesh: pass mesh= or enter a "
-            "mesh_compat.use_mesh(...) context"
+            "jax.sharding.set_mesh(...) context"
         )
     n_dp = 1
     for a in ("pod", "data"):
@@ -610,6 +611,14 @@ def init_error_feedback_state(
         p={**opt_state.p, "ef_psum": jnp.zeros((m, n_model * k), jnp.float32)},
         q={**opt_state.q, "ef_gather": jnp.zeros((n, n_dp * k), jnp.float32)},
     )
+
+
+def _first_occurrence(keys: jax.Array) -> jax.Array:
+    """(B,) bool, True at the first position of each distinct key."""
+    order = jnp.argsort(keys, stable=True)
+    sk = keys[order]
+    head = jnp.concatenate([jnp.ones((1,), bool), sk[1:] != sk[:-1]])
+    return jnp.zeros(keys.shape, bool).at[order].set(head)
 
 
 def train_step_shard_map(
@@ -652,8 +661,10 @@ def train_step_shard_map(
     residual its quantizer dropped (``init_error_feedback_state`` tables in
     ``opt_state``) and folds it into the next transmission of the same row
     — the EF-SGD recipe that keeps long-run convergence at fp32 quality.
-    Duplicate rows in one batch fold their residual deltas additively
-    (the same duplicate-accumulation caveat as the base step).
+    A row's residual rides on exactly one of its live (weight > 0)
+    occurrences in the batch: folded into every duplicate it would be sent
+    D times while banked once, and the residual would grow by a factor
+    (1 - D) per step — divergence on a skewed (Zipf) item stream.
 
     Collectives drop from O(n*k + B*k) all-reduce bytes to O(B_loc*k) —
     measured in EXPERIMENTS.md §Perf.  Semantics are identical to
@@ -664,13 +675,11 @@ def train_step_shard_map(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed import mesh_compat
-
-    mesh = mesh_compat.resolve_mesh(mesh)
-    if mesh is None:
+    mesh = mesh if mesh is not None else jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         raise ValueError(
             "train_step_shard_map needs a mesh: pass mesh= or enter a "
-            "mesh_compat.use_mesh(...) context"
+            "jax.sharding.set_mesh(...) context"
         )
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     n_dp = 1
@@ -731,8 +740,10 @@ def train_step_shard_map(
             # mesh-common scale (exact int8 summation), and bank what the
             # quantizer dropped back into the residual table.  The residual
             # update is a scatter-ADD of (partial - transmitted), so
-            # duplicate batch rows stay deterministic.
-            resid = ef_p[u_loc]
+            # duplicate batch rows stay deterministic; the old residual rides
+            # on one live occurrence of the row only.
+            carrier = _first_occurrence(jnp.where(wv[:, 0] > 0, u_loc, m_loc))
+            resid = ef_p[u_loc] * carrier[:, None]
             target = g_p_partial + resid
             local_max = jnp.max(jnp.abs(target))
             scale = jnp.maximum(
@@ -787,9 +798,15 @@ def train_step_shard_map(
 
                 if gc == "int8_ef":
                     # residual rows only exist for items this model rank
-                    # owns; non-owner rows transmit exact zeros as before
+                    # owns; non-owner rows transmit exact zeros as before,
+                    # and one live occurrence of an item carries its residual
+                    carrier = _first_occurrence(
+                        jnp.where(is_local & (w > 0), safe_i, n_loc)
+                    )
                     payload = jnp.where(
-                        is_local[:, None], dq_rows + ef_q[safe_i], 0.0
+                        is_local[:, None],
+                        dq_rows + ef_q[safe_i] * carrier[:, None],
+                        0.0,
                     )
                 else:
                     payload = dq_rows
@@ -854,7 +871,7 @@ def train_step_shard_map(
     if weight is None:
         weight = jnp.ones_like(batch["rating"], dtype=jnp.float32)
     new_p, new_q, acc_p, acc_q, ef_p_out, ef_q_out, abs_err, work = (
-        mesh_compat.shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(
@@ -936,14 +953,12 @@ def train_epoch_scan_shard_map(
     implementation.  ``batches`` follows the same ownership contract as the
     single step: every rating's user must live on its data shard's P block.
     """
-    from repro.distributed import mesh_compat
-
     _check_owner_compute_opt(opt_name)
-    mesh = mesh_compat.resolve_mesh(mesh)
-    if mesh is None:
+    mesh = mesh if mesh is not None else jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         raise ValueError(
             "train_epoch_scan_shard_map needs a mesh: pass mesh= or enter a "
-            "mesh_compat.use_mesh(...) context"
+            "jax.sharding.set_mesh(...) context"
         )
     return _train_epoch_scan_shard_map(
         params, opt_state, batches,

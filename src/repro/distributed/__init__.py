@@ -6,11 +6,6 @@ from repro.distributed.compression import (  # noqa: F401
     init_error_feedback,
     quantize_int8,
 )
-from repro.distributed.mesh_compat import (  # noqa: F401
-    get_abstract_mesh,
-    resolve_mesh,
-    use_mesh,
-)
 from repro.distributed.fault_tolerance import (  # noqa: F401
     FailureInjector,
     StepFailure,

@@ -97,7 +97,9 @@ def stream_topk_tiles(pm, q_tiles, b_tiles, offs, *, topk):
     O(m * (topk + block_n)) — the (m, n) score matrix is never materialized.
     Concatenating the running buffer FIRST makes ``lax.top_k``'s
     lowest-index tie preference resolve toward earlier item tiles, matching
-    the stable dense argsort oracle.  Shared by :func:`pruned_topk`
+    the stable dense argsort oracle; the score matmul is pinned to HIGHEST
+    precision like the oracle's (a TPU's default float32 matmul is not
+    float32-accurate).  Shared by :func:`pruned_topk`
     (``use_kernel=False``) and the serving engine's local + sharded paths —
     the tie-order subtlety lives in exactly one place.
     """
@@ -107,7 +109,7 @@ def stream_topk_tiles(pm, q_tiles, b_tiles, offs, *, topk):
     def merge(carry, tile):
         run_s, run_i = carry
         qt, bt, off = tile
-        s = pm @ qt.T + bt[None, :]
+        s = jnp.dot(pm, qt.T, precision=jax.lax.Precision.HIGHEST) + bt[None, :]
         gidx = off + jnp.arange(block_n, dtype=jnp.int32)
         cand_s = jnp.concatenate([run_s, s], axis=1)
         cand_i = jnp.concatenate(

@@ -26,31 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu naming moved across JAX versions; scratch VMEM spec lives here.
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-
-    def _compiler_params():
-        try:
-            return pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
-        except (AttributeError, TypeError):
-            try:
-                return pltpu.TPUCompilerParams(
-                    dimension_semantics=("parallel", "parallel", "arbitrary")
-                )
-            except (AttributeError, TypeError):
-                return None
-
-except ImportError:  # pragma: no cover - pallas.tpu always present on jax>=0.4
-    pltpu = None
-    _VMEM = None
-
-    def _compiler_params():
-        return None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(p_ref, q_ref, ru_ref, ri_ref, o_ref, acc_ref, *, block_k: int):
@@ -78,10 +54,12 @@ def _kernel(p_ref, q_ref, ru_ref, ri_ref, o_ref, acc_ref, *, block_k: int):
         tq_idx = t0 + jax.lax.broadcasted_iota(jnp.int32, (bn, bk), 1)
         pm = jnp.where(tp_idx < ru_ref[...], p_ref[...], 0.0).astype(jnp.float32)
         qm = jnp.where(tq_idx < ri_ref[...], q_ref[...], 0.0).astype(jnp.float32)
+        # HIGHEST: float32-accurate like the pinned XLA oracle it must match
         acc_ref[...] += jax.lax.dot_general(
             pm,
             qm,
             dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
 
@@ -111,16 +89,6 @@ def pruned_matmul_padded(
     grid = (m // block_m, n // block_n, k // block_k)
 
     kernel = functools.partial(_kernel, block_k=block_k)
-    if _VMEM is None:
-        raise RuntimeError(
-            "jax.experimental.pallas.tpu is unavailable on this jax install; "
-            "pruned_matmul_padded needs a pltpu.VMEM accumulator. Use the XLA "
-            "reference path instead (kernels.ops.pruned_matmul(use_kernel=False))."
-        )
-    scratch = [_VMEM((block_m, block_n), jnp.float32)]
-    params = _compiler_params()
-    kwargs = {"compiler_params": params} if params is not None else {}
-
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -132,7 +100,9 @@ def pruned_matmul_padded(
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda im, jn, ik: (im, jn)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
-        **kwargs,
     )(p, q, r_u, r_i)

@@ -32,8 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.pruned_matmul import _VMEM, pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = float("-inf")
 
@@ -43,21 +42,6 @@ _NEG_INF = float("-inf")
 TOPK_BLOCK_M = 128
 TOPK_BLOCK_N = 256
 TOPK_BLOCK_K = 128
-
-
-def _compiler_params():
-    """Unlike pruned_matmul, the N (item-tile) axis is sequential: it carries
-    the running top-k scratch.  Only the user-tile axis is parallel."""
-    if pltpu is None:
-        return None
-    semantics = ("parallel", "arbitrary", "arbitrary")
-    try:
-        return pltpu.CompilerParams(dimension_semantics=semantics)
-    except (AttributeError, TypeError):
-        try:
-            return pltpu.TPUCompilerParams(dimension_semantics=semantics)
-        except (AttributeError, TypeError):
-            return None
 
 
 def _merge_topk(run_s, run_i, tile_s, tile_i, topk: int):
@@ -124,9 +108,11 @@ def _kernel(
         tq_idx = t0 + jax.lax.broadcasted_iota(jnp.int32, (bn, bk), 1)
         pm = jnp.where(tp_idx < ru_ref[...], p_ref[...], 0.0).astype(jnp.float32)
         qm = jnp.where(tq_idx < ri_ref[...], q_ref[...], 0.0).astype(jnp.float32)
+        # HIGHEST: float32-accurate like the pinned XLA oracle it must match
         acc_ref[...] += jax.lax.dot_general(
             pm, qm,
             dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
 
@@ -176,18 +162,9 @@ def pruned_topk_padded(
     topk_pad = -(-topk // 128) * 128
     grid = (m // block_m, n // block_n, k // block_k)
 
-    if _VMEM is None:
-        raise RuntimeError(
-            "jax.experimental.pallas.tpu is unavailable on this jax install; "
-            "pruned_topk_padded needs pltpu.VMEM scratch. Use the streaming "
-            "XLA path instead (kernels.ops.pruned_topk(use_kernel=False))."
-        )
     kernel = functools.partial(
         _kernel, block_k=block_k, topk=topk, n_items=n_items
     )
-    params = _compiler_params()
-    kwargs = {"compiler_params": params} if params is not None else {}
-
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -207,10 +184,14 @@ def pruned_topk_padded(
             jax.ShapeDtypeStruct((m, topk_pad), jnp.int32),
         ],
         scratch_shapes=[
-            _VMEM((block_m, block_n), jnp.float32),
-            _VMEM((block_m, topk_pad), jnp.float32),
-            _VMEM((block_m, topk_pad), jnp.int32),
+            pltpu.VMEM((block_m, block_n), jnp.float32),
+            pltpu.VMEM((block_m, topk_pad), jnp.float32),
+            pltpu.VMEM((block_m, topk_pad), jnp.int32),
         ],
+        # the N (item-tile) axis carries the running top-k scratch, so only
+        # the user-tile axis is parallel
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+        ),
         interpret=interpret,
-        **kwargs,
     )(p, q, r_u, r_i, bias)

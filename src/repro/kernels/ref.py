@@ -30,11 +30,16 @@ def pruned_matmul_ref(
     """All-pairs early-stopped product: out[u, i] = sum_{t < min(r_u, r_i)}.
 
     Masking each operand by its own rank makes the product mask the AND of
-    the two prefix masks, i.e. exactly ``t < min(r_u, r_i)``.
+    the two prefix masks, i.e. exactly ``t < min(r_u, r_i)``.  Precision is
+    pinned to HIGHEST: the oracle must be float32-accurate, and a TPU's
+    default float32 matmul precision is lower (no-op on the CPU).
     """
     pm = masked_factors(p, r_u).astype(jnp.float32)
     qm = masked_factors(q, r_i).astype(jnp.float32)
-    return jnp.dot(pm, qm.T, preferred_element_type=jnp.float32).astype(out_dtype)
+    return jnp.dot(
+        pm, qm.T, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    ).astype(out_dtype)
 
 
 def pruned_topk_ref(

@@ -110,11 +110,9 @@ def run_cell(
     from repro.distributed.sharding import sanitize_shardings
 
     in_sh = sanitize_shardings(cell.in_shardings(mesh), cell.abstract_args)
-    # use_mesh (not a bare `with mesh:`) so shard_map variants can resolve
-    # the ambient mesh at trace time on every jax version.
-    from repro.distributed.mesh_compat import use_mesh
-
-    with use_mesh(mesh):
+    # set_mesh (not a bare `with mesh:`) so shard_map variants can resolve
+    # the ambient mesh at trace time.
+    with jax.sharding.set_mesh(mesh):
         jitted = jax.jit(
             cell.step_fn,
             in_shardings=in_sh,

@@ -46,6 +46,7 @@ import numpy as np
 from repro.core.trainer import DPMFTrainer, TrainConfig
 from repro.data.ratings import paper_dataset, train_test_split
 from repro.eval import PrequentialEvaluator, recalibration_hook
+from repro.launch.cache import configure_compile_cache
 from repro.online import (
     OnlineUpdater,
     PoissonSource,
@@ -354,7 +355,7 @@ def run_online(args) -> dict:
     return report
 
 
-def main() -> None:
+def main(argv=None) -> None:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--dataset", default="movielens100k",
                         choices=["movielens100k", "appliances",
@@ -389,7 +390,8 @@ def main() -> None:
     parser.add_argument("--replica-backend", choices=("local", "process"),
                         default="local",
                         help="fleet replicas in-process or as spawned "
-                             "multiprocessing children")
+                             "multiprocessing children (CPU only: a TPU "
+                             "chip belongs to one process)")
     parser.add_argument("--supervise", action="store_true",
                         help="run a FleetSupervisor: heartbeat probes, "
                              "failover routing, auto-respawn of dead "
@@ -421,7 +423,8 @@ def main() -> None:
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--json", default=None, metavar="PATH",
                         help="also write the run report to PATH")
-    args = parser.parse_args()
+    args = parser.parse_args(argv)
+    configure_compile_cache()
 
     report = run_online(args)
     print(json.dumps(report, indent=2))

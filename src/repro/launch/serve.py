@@ -44,6 +44,7 @@ import time
 
 import numpy as np
 
+from repro.launch.cache import configure_compile_cache
 from repro.serving import (
     LatencyWindow,
     QueueFullError,
@@ -283,7 +284,8 @@ def main() -> None:
     parser.add_argument("--replica-backend", choices=("local", "process"),
                         default="local",
                         help="fleet replicas in-process or as spawned "
-                             "multiprocessing children")
+                             "multiprocessing children (CPU only: a TPU "
+                             "chip belongs to one process)")
     parser.add_argument("--routing", choices=("affinity", "least", "random"),
                         default="affinity",
                         help="fleet routing policy (see serving/fleet/router)")
@@ -298,6 +300,7 @@ def main() -> None:
     parser.add_argument("--slo-tick-ms", type=float, default=100.0,
                         help="controller tick interval (ms)")
     args = parser.parse_args()
+    configure_compile_cache()
 
     params, t_p, t_q, _, meta = load_mf_checkpoint(args.ckpt)
     user_history = None if args.history is None else np.load(args.history)

@@ -20,6 +20,7 @@ from repro.distributed.fault_tolerance import (
     StragglerDetector,
     run_with_retries,
 )
+from repro.launch.cache import configure_compile_cache
 
 
 def main() -> None:
@@ -78,6 +79,7 @@ def main() -> None:
                         help="mid-epoch checkpoint every N slabs (store "
                              "mode; 0 = epoch boundaries only)")
     args = parser.parse_args()
+    configure_compile_cache()
 
     train_ds = test_ds = None
     if args.store_dir is None or args.build_store:

@@ -67,12 +67,9 @@ def moe_ffn(
     dispatch EP formulation (moe_ffn_shard_map) when an ambient mesh with a
     "model" axis is set; otherwise falls back to the XLA-SPMD path."""
     if use_shard_map:
-        from repro.distributed import mesh_compat
-
-        am = mesh_compat.get_abstract_mesh()
+        am = jax.sharding.get_abstract_mesh()
         if (
-            am is not None
-            and "model" in getattr(am, "axis_names", ())
+            "model" in am.axis_names
             and cfg.num_experts % am.shape["model"] == 0
         ):
             return moe_ffn_shard_map(x, params, cfg, activation=activation, mesh=am)
@@ -175,13 +172,11 @@ def moe_ffn_shard_map(
 ) -> Tuple[jax.Array, jax.Array]:
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed import mesh_compat
-
-    mesh = mesh_compat.resolve_mesh(mesh)
-    if mesh is None:
+    mesh = mesh if mesh is not None else jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         raise ValueError(
             "moe_ffn_shard_map needs a mesh: pass mesh= or enter a "
-            "mesh_compat.use_mesh(...) context"
+            "jax.sharding.set_mesh(...) context"
         )
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     n_model = mesh.shape["model"]
@@ -244,7 +239,7 @@ def moe_ffn_shard_map(
         out = jax.lax.psum(out_loc, "model").astype(x_blk.dtype)
         return out, aux[None]
 
-    out, aux = mesh_compat.shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

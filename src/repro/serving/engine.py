@@ -955,7 +955,6 @@ class ServingEngine:
         once: jit caches by function identity, so rebuilding the closure per
         request would retrace and recompile every call.  Layouts enter as
         arguments, so the program survives hot swaps."""
-        from repro.distributed import mesh_compat
         from repro.distributed.sharding import (
             serving_topk_kernel_specs,
             serving_topk_specs,
@@ -994,7 +993,7 @@ class ServingEngine:
                         local_s, local_i, pm_blk.shape[0], topk
                     )
 
-            self._sharded_fns[key] = jax.jit(mesh_compat.shard_map(
+            self._sharded_fns[key] = jax.jit(jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=in_specs,
@@ -1021,14 +1020,13 @@ class ServingEngine:
         indices)`` like :meth:`topk`; requests go through the same
         chunk/bucket loop, so batch shapes (and thus compiled programs) stay
         bounded."""
-        from repro.distributed import mesh_compat
         from repro.distributed.sharding import serving_row_multiple
 
         snap = self._snap
         ids = self._validate_for(snap, user_ids, topk)
         ids, evicted = self._translate_ids(snap, ids)
-        mesh = mesh_compat.resolve_mesh(mesh)
-        if mesh is None or "model" not in mesh.axis_names:
+        mesh = mesh if mesh is not None else jax.sharding.get_abstract_mesh()
+        if "model" not in mesh.axis_names:
             raise ValueError("topk_sharded needs a mesh with a 'model' axis")
         n_model = mesh.shape["model"]
         kernel = self.use_kernel

@@ -12,7 +12,9 @@ Two implementations of the same contract (``replica_id``, ``version``,
 * :class:`ProcessReplica` — a ``multiprocessing`` (spawn) child running a
   ``LocalReplica``, talked to over a duplex pipe.  Spawn (not fork) so the
   child re-imports cleanly next to JAX's threadpools — the only mode safe
-  on CPU CI.  The child bootstraps either from a ``kind=full``
+  on CPU CI.  CPU only: on a TPU host the chip belongs to the parent
+  process, so a child could never reach it — construction refuses at once
+  there instead of hanging until ``start_timeout``.  The child bootstraps either from a ``kind=full``
   :class:`~repro.serving.fleet.bus.DeltaMessage` or from a checkpoint
   directory (training base + ``fold_deltas`` over the online delta chain —
   the late-join path, which leaves the replica at the chain's last version
@@ -29,6 +31,7 @@ import threading
 from concurrent.futures import Future
 from typing import Any, Dict, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.serving.fleet import bus
@@ -314,6 +317,13 @@ class ProcessReplica:
         queue_kwargs: Optional[dict] = None,
         start_timeout: float = 180.0,
     ):
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "process replicas cannot run on a TPU host: a chip belongs to "
+                "one process and this one already holds it, so a spawned "
+                "replica would fail or hang reaching for it; use "
+                "backend='local' (in-process replicas) instead"
+            )
         if (init_msg is None) == (checkpoint is None):
             raise ValueError("pass exactly one of init_msg / checkpoint")
         init = {"msg": init_msg} if init_msg is not None else {

@@ -21,6 +21,7 @@ from repro.distributed import (
     run_with_retries,
 )
 from repro.distributed.sharding import sanitize_shardings
+from repro.launch.mesh import make_mesh
 
 
 def test_quantize_roundtrip_error_bound():
@@ -71,7 +72,7 @@ def test_microbatch_grads_match_full_batch():
 def test_sanitize_shardings_downgrades_indivisible():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     sh = NamedSharding(mesh, P("model", None))
     aval = jax.ShapeDtypeStruct((7, 3), jnp.float32)
     fixed = sanitize_shardings(sh, aval)

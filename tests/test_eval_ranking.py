@@ -12,6 +12,7 @@ from repro.core import mf
 from repro.core.trainer import DPMFTrainer, TrainConfig
 from repro.data import synthetic_ratings, train_test_split
 from repro.eval import ranking as R
+from repro.launch.mesh import make_mesh
 from repro.serving import ServingEngine
 
 
@@ -396,7 +397,7 @@ def test_evaluate_engine_sharded_matches_oracle_4device_mesh():
         pytest.skip("needs >= 4 devices (run under the 4-device CI mesh job)")
     params, ds = _random_setup(m=33, n=640, k=16)
     for shape, names in [((4,), ("model",)), ((2, 2), ("data", "model"))]:
-        mesh = jax.make_mesh(shape, names)
+        mesh = make_mesh(shape, names)
         engine = ServingEngine(params, 0.0, 0.0, use_kernel=False,
                                max_batch=16)
         got = R.evaluate_engine(engine, ds, topk=8, mesh=mesh)
@@ -418,7 +419,7 @@ def test_workload_vectors_sharded_match_oracle_4device_mesh():
     if len(jax.devices()) < 4:
         pytest.skip("needs >= 4 devices (run under the 4-device CI mesh job)")
     trainer, test = _implicit_trained(seed=2)
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
     engine = ServingEngine(trainer.params, 0.0, 0.0, use_kernel=False,
                            max_batch=16)
     got = R.evaluate_engine(engine, test, topk=8, mesh=mesh)

@@ -567,6 +567,18 @@ def test_engine_stop_rejects_concurrent_submits():
 # ---------------------------------------------------------------------------
 
 
+def test_process_fleet_refuses_tpu_backend(monkeypatch):
+    """On a TPU host the parent holds the chip, so a spawned replica could
+    never reach it: the process backend must refuse at once (no child
+    started, no wait for start_timeout) and say why."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    start = time.monotonic()
+    with pytest.raises(RuntimeError, match="TPU host"):
+        ServingFleet(_params(30, 200), 0.0, 0.0, replicas=2,
+                     backend="process")
+    assert time.monotonic() - start < 10.0
+
+
 @pytest.mark.slow
 def test_process_fleet_replicates_and_drains():
     rng = np.random.default_rng(6)

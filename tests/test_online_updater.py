@@ -8,6 +8,7 @@ import pytest
 from repro.core import mf
 from repro.core.trainer import DPMFTrainer, TrainConfig
 from repro.data import build_user_history, synthetic_ratings, train_test_split
+from repro.launch.mesh import make_mesh
 from repro.online import EventBatch, OnlineUpdater
 from repro.optim.optimizers import RowOptimizer
 
@@ -381,10 +382,9 @@ def test_mesh_mode_updater_matches_single_device():
 
     if jax.device_count() < 4:
         pytest.skip("needs >= 4 devices (run under the 4-device CI mesh job)")
-    from repro.distributed.mesh_compat import use_mesh
     from repro.online.stream import EventBatch
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     m, n, k = 16, 8, 12
     params = mf.init_params(jax.random.PRNGKey(0), m, n, k)
     rng = np.random.default_rng(3)
@@ -409,7 +409,7 @@ def test_mesh_mode_updater_matches_single_device():
 
     ref_upd = OnlineUpdater(params, None, 0.05, 0.05, optimizer="adagrad",
                             lr=0.03, batch_size=64, seed=9)
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         mesh_upd = OnlineUpdater(params, None, 0.05, 0.05,
                                  optimizer="adagrad", lr=0.03,
                                  batch_size=64, seed=9, mesh=mesh)

@@ -16,6 +16,7 @@ from repro.core.ranks import effective_ranks
 from repro.core.trainer import DPMFTrainer, TrainConfig
 from repro.data import synthetic_ratings, train_test_split
 from repro.kernels import ops, ref
+from repro.launch.mesh import make_mesh
 from repro.serving import (
     LRUCache,
     MicroBatcher,
@@ -329,7 +330,7 @@ def test_engine_validates_topk_bounds():
         with pytest.raises(ValueError, match=r"topk must be in \[1, 64\]"):
             engine.topk([0], bad)
         with pytest.raises(ValueError, match=r"topk must be in \[1, 64\]"):
-            engine.topk_sharded([0], bad, mesh=jax.make_mesh((1,), ("model",)))
+            engine.topk_sharded([0], bad, mesh=make_mesh((1,), ("model",)))
     s, i = engine.topk([0], 64)  # the boundary itself works
     assert s.shape == (1, 64) and i.shape == (1, 64)
 
@@ -358,7 +359,7 @@ def test_sharded_topk_single_device_mesh():
     params = mf.init_params(jax.random.PRNGKey(4), 24, 500, 16,
                             variant="bias", global_mean=3.0)
     engine = ServingEngine(params, 0.03, 0.03, use_kernel=False, block_n=128)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     users = np.arange(10, dtype=np.int32)
     want_s, want_i = engine.topk(users, 6)
     got_s, got_i = engine.topk_sharded(users, 6, mesh=mesh)
@@ -382,7 +383,7 @@ def test_sharded_topk_2d_mesh_inprocess():
         ((2, 2), ("data", "model")),   # 2-D: users x items
         ((4, 1), ("data", "model")),   # degenerate: users only
     ]:
-        mesh = jax.make_mesh(shape, names)
+        mesh = make_mesh(shape, names)
         got_s, got_i = engine.topk_sharded(users, 6, mesh=mesh)
         assert np.array_equal(want_i, got_i), (shape, names)
         np.testing.assert_allclose(want_s, got_s, rtol=1e-5, atol=1e-5)
@@ -397,6 +398,7 @@ def test_sharded_topk_multi_device():
     code = """
         import numpy as np, jax
         from repro.core import mf
+        from repro.launch.mesh import make_mesh
         from repro.serving import ServingEngine
         params = mf.init_params(jax.random.PRNGKey(0), 48, 2100, 24,
                                 variant="bias", global_mean=3.0)
@@ -407,12 +409,12 @@ def test_sharded_topk_multi_device():
         for shape, names in [((8,), ("model",)),
                              ((2, 4), ("data", "model")),
                              ((4, 2), ("data", "model"))]:
-            mesh = jax.make_mesh(shape, names)
+            mesh = make_mesh(shape, names)
             got_s, got_i = engine.topk_sharded(users, 9, mesh=mesh)
             assert np.array_equal(want_i, got_i), (shape, names)
             np.testing.assert_allclose(want_s, got_s, rtol=1e-5, atol=1e-5)
         # bucket 1 < data extent: the engine must pad the user slab
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         one_s, one_i = engine.topk_sharded(users[3:4], 9, mesh=mesh)
         assert np.array_equal(one_i, want_i[3:4])
         np.testing.assert_allclose(one_s, want_s[3:4], rtol=1e-5, atol=1e-5)
@@ -437,7 +439,7 @@ def test_sharded_topk_kernel_path_single_device_mesh():
                             variant="bias", global_mean=3.0)
     engine = ServingEngine(params, 0.03, 0.03, use_kernel=True,
                            interpret=True, max_batch=16)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     users = np.arange(9, dtype=np.int32)
     want_s, want_i = _dense_oracle(
         params, jnp.asarray(users), 0.03, 0.03, 6
@@ -468,7 +470,7 @@ def test_sharded_topk_kernel_path_4device_mesh():
         ((2, 2), ("data", "model")),   # 2-D: users x items
         ((4, 1), ("data", "model")),   # degenerate: users only
     ]:
-        mesh = jax.make_mesh(shape, names)
+        mesh = make_mesh(shape, names)
         got_s, got_i = engine.topk_sharded(users, 7, mesh=mesh)
         assert np.array_equal(want_i, got_i), (shape, names)
         np.testing.assert_allclose(want_s, got_s, rtol=1e-5, atol=1e-5)

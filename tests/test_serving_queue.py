@@ -13,6 +13,7 @@ import jax
 import pytest
 
 from repro.core import mf
+from repro.launch.mesh import make_mesh
 from repro.serving import (
     QueueFullError,
     RequestQueue,
@@ -302,7 +303,7 @@ def test_engine_queue_sharded_scoring_parity(engine):
     """Queue-fed scoring through topk_sharded on a 1-way mesh must equal the
     local sync path bitwise (the 2-D layouts are covered on the 4-device CI
     mesh and the slow subprocess test in test_serving.py)."""
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     engine.start(mesh=mesh)
     try:
         futs = [engine.submit(u, 6) for u in (0, 9, 33)]

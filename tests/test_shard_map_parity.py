@@ -27,17 +27,17 @@ def _run(code: str) -> str:
 def test_moe_shard_map_matches_xla_path():
     out = _run("""
         import jax, jax.numpy as jnp
-        from repro.distributed.mesh_compat import use_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.moe import (MoEConfig, init_moe_params,
                                       moe_ffn_xla, moe_ffn_shard_map)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = MoEConfig(num_experts=8, top_k=2, d_ff=32, num_shared=1,
                         capacity_factor=8.0)  # dropless => exact parity
         params = init_moe_params(jax.random.PRNGKey(0), 64, cfg,
                                  dtype=jnp.float32)
         x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (64, 64))
         ref, _ = moe_ffn_xla(x, params, cfg)
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             got, _ = jax.jit(lambda x, p: moe_ffn_shard_map(
                 x, p, cfg, mesh=mesh.abstract_mesh))(x, params)
         diff = float(jnp.max(jnp.abs(ref - got)))
@@ -49,7 +49,7 @@ def test_moe_shard_map_matches_xla_path():
         def loss_ref(p, x):
             o, _ = moe_ffn_xla(x, p, cfg)
             return jnp.sum(o ** 2)
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             g1 = jax.jit(jax.grad(loss_sm))(params, x)
         g2 = jax.grad(loss_ref)(params, x)
         for key in ("wg", "wi", "wo", "router"):
@@ -65,9 +65,9 @@ def test_mf_owner_compute_bit_exact():
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import mf
-        from repro.distributed.mesh_compat import use_mesh
+        from repro.launch.mesh import make_mesh
         from repro.optim.optimizers import RowOptimizer
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         m, n, k, B = 16, 8, 12, 16
         rng = np.random.default_rng(0)
         params = mf.init_params(jax.random.PRNGKey(0), m, n, k)
@@ -86,7 +86,7 @@ def test_mf_owner_compute_bit_exact():
                 ref_p, ref_s, _ = mf.train_step(
                     params, state, batch, jnp.float32(t), jnp.float32(t),
                     jnp.float32(0.05), jnp.ones((k,)), opt=opt, lam=0.02)
-                with use_mesh(mesh):
+                with jax.sharding.set_mesh(mesh):
                     sm_p, sm_s, _ = jax.jit(
                         lambda p, s, b, tp, tq: mf.train_step_shard_map(
                             p, s, b, tp, tq, lr=0.05, lam=0.02,
@@ -128,10 +128,10 @@ def test_int8_error_feedback_tracks_fp32():
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import mf
-        from repro.distributed.mesh_compat import use_mesh
+        from repro.launch.mesh import make_mesh
         from repro.optim.optimizers import RowOptimizer
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         m, n, k, B, steps, epochs = 16, 8, 12, 16, 40, 3
         rng = np.random.default_rng(0)
         params0 = mf.init_params(jax.random.PRNGKey(0), m, n, k)
@@ -153,11 +153,11 @@ def test_int8_error_feedback_tracks_fp32():
         def final_err(gc):
             state = mf.init_opt_state(params0, opt)
             if gc == "int8_ef":
-                with use_mesh(mesh):
+                with jax.sharding.set_mesh(mesh):
                     state = mf.init_error_feedback_state(
                         params0, state, mesh)
             params = params0
-            with use_mesh(mesh):
+            with jax.sharding.set_mesh(mesh):
                 for _ in range(epochs):
                     params, state, metrics = mf.train_epoch_scan_shard_map(
                         params, state, batches, 0.0, 0.0, lr=0.05,
@@ -180,3 +180,59 @@ def test_int8_error_feedback_tracks_fp32():
         print("INT8_EF_OK")
     """)
     assert "INT8_EF_OK" in out
+
+
+@pytest.mark.slow
+def test_int8_error_feedback_stable_on_skewed_items():
+    """A Zipf-like item stream puts the same item row in a batch many
+    times over.  Each row's quantization residual must ride on one
+    occurrence only: folded into all D duplicates it is sent D times but
+    banked once, grows by (1 - D) per step and blows up to NaN."""
+    out = _run("""
+        import numpy as np, jax, jax.numpy as jnp
+        from repro.core import mf
+        from repro.launch.mesh import make_mesh
+        from repro.optim.optimizers import RowOptimizer
+
+        mesh = make_mesh((2, 2), ("data", "model"))
+        m, n, k, B, steps = 32, 64, 16, 64, 30
+        rng = np.random.default_rng(1)
+        users = np.stack([
+            np.concatenate([rng.integers(s * 16, (s + 1) * 16, B // 2)
+                            for s in range(2)])
+            for _ in range(steps)
+        ]).astype(np.int32)
+        # half of every batch rates item 0, the rest a geometric tail
+        items = np.where(rng.random((steps, B)) < 0.5, 0,
+                         np.minimum(rng.geometric(0.2, (steps, B)), n - 1))
+        batches = {
+            "user": jnp.asarray(users),
+            "item": jnp.asarray(items.astype(np.int32)),
+            "rating": jnp.asarray(
+                rng.uniform(0, 10, (steps, B)).astype(np.float32)),
+        }
+        params0 = mf.init_params(jax.random.PRNGKey(0), m, n, k)
+        opt = RowOptimizer(name="adagrad")
+
+        def final(gc):
+            params = jax.tree.map(jnp.copy, params0)
+            state = mf.init_opt_state(params, opt)
+            with jax.sharding.set_mesh(mesh):
+                if gc == "int8_ef":
+                    state = mf.init_error_feedback_state(params, state, mesh)
+                for _ in range(2):
+                    params, state, metrics = mf.train_epoch_scan_shard_map(
+                        params, state, batches, 0.0, 0.0, lr=0.05, lam=0.02,
+                        opt_name="adagrad", grad_compression=gc, mesh=mesh)
+            return params, state, float(metrics["abs_err"])
+
+        _, _, fp32 = final("none")
+        params, state, ef = final("int8_ef")
+        print("fp32", fp32, "ef", ef)
+        assert np.isfinite(ef), ef
+        assert bool(jnp.isfinite(params.q).all() & jnp.isfinite(params.p).all())
+        assert bool(jnp.isfinite(state.q["ef_gather"]).all())
+        assert abs(ef - fp32) / fp32 < 0.01, (ef, fp32)
+        print("INT8_EF_SKEW_OK")
+    """)
+    assert "INT8_EF_SKEW_OK" in out

@@ -15,6 +15,7 @@ from repro.core import mf
 from repro.data import build_user_history, synthetic_ratings
 from repro.data import loader
 from repro.kernels import fused_mf_sgd, ref
+from repro.launch.mesh import make_mesh
 from repro.optim.optimizers import RowOptimizer
 
 OPTIMIZERS = ("sgd", "momentum", "adagrad", "adadelta", "adam")
@@ -282,10 +283,9 @@ def test_scan_shard_map_matches_single_device():
     mesh (owner-routed batches, adagrad)."""
     if jax.device_count() < 4:
         pytest.skip("needs >= 4 devices (run under the 4-device CI mesh job)")
-    from repro.distributed.mesh_compat import use_mesh
     from repro.distributed.sharding import route_batch_to_owner_shards
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     m, n, k, B, steps = 16, 8, 12, 16, 4
     rng = np.random.default_rng(0)
     routed_steps = []
@@ -332,7 +332,7 @@ def test_scan_shard_map_matches_single_device():
     )
     params2 = mf.init_params(jax.random.PRNGKey(0), m, n, k)
     state2 = mf.init_opt_state(params2, opt)
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         got_p, got_s, got_m = mf.train_epoch_scan_shard_map(
             params2, state2, routed, 0.05, 0.05, lr=0.05, lam=0.02,
             opt_name="adagrad", mesh=mesh.abstract_mesh,

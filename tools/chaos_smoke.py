@@ -14,7 +14,11 @@ Two gates over ONE two-process fleet, each fatal on failure:
    heal, and every replica's full served state must end bitwise equal
    to a fault-free in-process shadow fed the same messages.
 
-Usage:  PYTHONPATH=src python tools/chaos_smoke.py
+The drill spawns replica *processes*, so it runs on the CPU only: on a
+TPU host a chip belongs to one process, and ``ServingFleet(backend=
+"process")`` refuses to start there.
+
+Usage:  JAX_PLATFORMS=cpu PYTHONPATH=src python tools/chaos_smoke.py
 """
 from __future__ import annotations
 

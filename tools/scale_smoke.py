@@ -10,9 +10,11 @@ Three gates, each fatal on failure:
    partway through epoch 1, restores from the mid-epoch checkpoint, and
    asserts every parameter/optimizer array AND the logged epoch metrics are
    bitwise identical to the uninterrupted run;
-3. **eviction-armed online launcher** — runs ``repro.launch.online`` with
-   ``--evict-max-users`` small enough that the poisson new-user stream
-   forces live eviction/compaction rounds, and checks the report says so.
+3. **eviction-armed online launcher** — runs ``repro.launch.online``'s
+   entry point in this process (a child would need the accelerator this
+   process already holds) with ``--evict-max-users`` small enough that the
+   poisson new-user stream forces live eviction/compaction rounds, and
+   checks the report says so.
 
 Usage:  PYTHONPATH=src python tools/scale_smoke.py
 """
@@ -21,7 +23,6 @@ from __future__ import annotations
 import json
 import os
 import shutil
-import subprocess
 import sys
 import tempfile
 
@@ -32,6 +33,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import repro.core.trainer as trainer_lib
 from repro.core.trainer import DPMFTrainer, TrainConfig
 from repro.data import synthetic_ratings
+from repro.launch import online as online_launch
 from repro.store import RatingsStore, build_store
 
 
@@ -122,22 +124,13 @@ def main() -> int:
         # ---- gate 3: online launcher with eviction armed -----------------
         print("[3/3] launch.online with cold-row eviction armed")
         report_path = os.path.join(workdir, "online_report.json")
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), "..", "src")
-        proc = subprocess.run(
-            [sys.executable, "-m", "repro.launch.online",
-             "--train-epochs", "2", "--events", "640", "--batch-events", "16",
+        # exits through SystemExit (non-zero) on any failed request
+        online_launch.main(
+            ["--train-epochs", "2", "--events", "640", "--batch-events", "16",
              "--swap-every", "4", "--source", "poisson",
              "--new-id-prob", "0.5", "--evict-max-users", "60",
-             "--json", report_path],
-            env=env, capture_output=True, text=True, timeout=900,
+             "--json", report_path]
         )
-        sys.stdout.write(proc.stdout[-2000:])
-        if proc.returncode != 0:
-            sys.stderr.write(proc.stderr[-4000:])
-            print("FAIL: launch.online exited nonzero")
-            return 1
         with open(report_path) as f:
             report = json.load(f)
         ev = report.get("eviction")
