@@ -1,0 +1,3 @@
+"""Device idle share of the traced window, in the train_job cells
+(moves ``job_ratings_per_s``); see bench/train_metrics.py."""
+from bench.train_metrics import idle_share as read  # noqa: F401

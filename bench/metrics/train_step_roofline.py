@@ -1,0 +1,3 @@
+"""The training step's share of its roofline, in the train_epochs cells
+(moves ``train_ratings_per_s``); see bench/train_metrics.py."""
+from bench.train_metrics import step_roofline as read  # noqa: F401

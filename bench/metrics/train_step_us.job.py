@@ -1,0 +1,3 @@
+"""Device time of one training step, in the train_job cells
+(moves ``job_ratings_per_s``); see bench/train_metrics.py."""
+from bench.train_metrics import step_us as read  # noqa: F401
