@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import checkpoint as ckpt_lib
+from repro import tracing
 from repro.core import mf, rearrange, threshold
 from repro.data import loader
 from repro.data.ratings import RatingsDataset, build_user_history
@@ -116,148 +117,149 @@ class DPMFTrainer:
         train_ds: Optional[RatingsDataset] = None,
         test_ds: Optional[RatingsDataset] = None,
     ):
-        self.config = config
-        self.opt = RowOptimizer(name=config.optimizer)
-        if config.epoch_mode not in ("scan", "python"):
-            raise ValueError(f"unknown epoch_mode {config.epoch_mode!r}")
-        if config.objective not in ("explicit", "implicit", "bpr"):
-            raise ValueError(f"unknown objective {config.objective!r}")
-        self._train_weight = None      # implicit confidence column
-        self._bpr_sampler = None
-        if config.objective != "explicit":
-            if config.store_dir is not None:
-                raise ValueError(
-                    "store-backed training supports only the explicit "
-                    "objective"
-                )
-            if config.epoch_mode != "scan":
-                raise ValueError(
-                    f"objective {config.objective!r} requires "
-                    "epoch_mode='scan'"
-                )
-            if config.variant == "svdpp":
-                raise ValueError(
-                    "svdpp histories assume a rated log; use variant "
-                    "'funk' or 'bias' with implicit/bpr objectives"
-                )
-            if train_ds is None:
-                raise ValueError(
-                    f"objective {config.objective!r} requires train_ds"
-                )
-        if config.objective == "implicit":
-            from repro.workloads import implicit as implicit_wl
+        with tracing.span("repro.trainer.init"):
+            self.config = config
+            self.opt = RowOptimizer(name=config.optimizer)
+            if config.epoch_mode not in ("scan", "python"):
+                raise ValueError(f"unknown epoch_mode {config.epoch_mode!r}")
+            if config.objective not in ("explicit", "implicit", "bpr"):
+                raise ValueError(f"unknown objective {config.objective!r}")
+            self._train_weight = None      # implicit confidence column
+            self._bpr_sampler = None
+            if config.objective != "explicit":
+                if config.store_dir is not None:
+                    raise ValueError(
+                        "store-backed training supports only the explicit "
+                        "objective"
+                    )
+                if config.epoch_mode != "scan":
+                    raise ValueError(
+                        f"objective {config.objective!r} requires "
+                        "epoch_mode='scan'"
+                    )
+                if config.variant == "svdpp":
+                    raise ValueError(
+                        "svdpp histories assume a rated log; use variant "
+                        "'funk' or 'bias' with implicit/bpr objectives"
+                    )
+                if train_ds is None:
+                    raise ValueError(
+                        f"objective {config.objective!r} requires train_ds"
+                    )
+            if config.objective == "implicit":
+                from repro.workloads import implicit as implicit_wl
 
-            # one-time expansion: positives + sampled negatives, with the
-            # WALS confidence column carried as per-example weights
-            train_ds, self._train_weight = implicit_wl.implicit_dataset(
-                train_ds,
-                alpha=config.implicit_alpha,
-                negatives=config.implicit_negatives,
-                seed=config.seed,
-            )
-            if test_ds is not None:
-                # held-out interactions as preference-1 targets: test MAE
-                # reads "distance from 1 on the user's actual items"
-                test_ds = implicit_wl.binarize_positives(test_ds)
-        self.train_ds = train_ds
-        self.test_ds = test_ds
-        self._store = None
-        self._loader = None
-        self._resume_slab = 0
-        self._resume_sums = (0.0, 0.0, 0)   # (err_sum, work_sum, steps_done)
-        # slab-level fault tolerance: wall-time outlier detection feeding
-        # the epoch record, plus an optional test-injected failure source
-        # (FailureInjector) exercised under TrainConfig.max_step_retries
-        self.straggler = StragglerDetector(window=20, z_threshold=4.0)
-        self.failure_injector = None
-        self._slab_counter = 0              # global slab index across epochs
-        if config.store_dir is not None:
-            # Out-of-core path: the ratings stay on disk (mmap) and stream
-            # through a bounded prefetch queue as (slab_steps, B) slabs —
-            # host memory is bounded by the queue depth, not the dataset.
-            from repro.store import RatingsStore, ShardedRatingsLoader
-
-            if config.epoch_mode != "scan":
-                raise ValueError("store-backed training requires epoch_mode='scan'")
-            if config.variant == "svdpp":
-                raise ValueError(
-                    "store-backed training does not support svdpp (the "
-                    "implicit-history matrix is itself O(users))"
-                )
-            self._store = RatingsStore(config.store_dir)
-            self._loader = ShardedRatingsLoader(
-                self._store,
-                config.batch_size,
-                slab_steps=config.slab_steps,
-                prefetch=config.prefetch_slabs,
-            )
-        elif train_ds is None:
-            raise ValueError("either train_ds or config.store_dir is required")
-        self.hist = (
-            build_user_history(train_ds, config.max_hist)
-            if config.variant == "svdpp"
-            else None
-        )
-        if config.epoch_mode == "scan":
-            # Upload the ratings (and eval set / SVD++ history) ONCE;
-            # per-epoch reshuffles happen on device (data/loader.py).  The
-            # batch size is clamped so a tiny dataset trains as one batch
-            # per epoch instead of degenerating to zero steps (which is
-            # what the drop-remainder host loop silently does).  In store
-            # mode the train table never lands on device wholesale.
-            self._packed_train = (
-                loader.pack_ratings(
+                # one-time expansion: positives + sampled negatives, with the
+                # WALS confidence column carried as per-example weights
+                train_ds, self._train_weight = implicit_wl.implicit_dataset(
                     train_ds,
-                    min(config.batch_size, max(len(train_ds), 1)),
-                    weight=self._train_weight,
+                    alpha=config.implicit_alpha,
+                    negatives=config.implicit_negatives,
+                    seed=config.seed,
                 )
-                if self._loader is None and config.objective != "bpr"
+                if test_ds is not None:
+                    # held-out interactions as preference-1 targets: test MAE
+                    # reads "distance from 1 on the user's actual items"
+                    test_ds = implicit_wl.binarize_positives(test_ds)
+            self.train_ds = train_ds
+            self.test_ds = test_ds
+            self._store = None
+            self._loader = None
+            self._resume_slab = 0
+            self._resume_sums = (0.0, 0.0, 0)   # (err_sum, work_sum, steps_done)
+            # slab-level fault tolerance: wall-time outlier detection feeding
+            # the epoch record, plus an optional test-injected failure source
+            # (FailureInjector) exercised under TrainConfig.max_step_retries
+            self.straggler = StragglerDetector(window=20, z_threshold=4.0)
+            self.failure_injector = None
+            self._slab_counter = 0              # global slab index across epochs
+            if config.store_dir is not None:
+                # Out-of-core path: the ratings stay on disk (mmap) and stream
+                # through a bounded prefetch queue as (slab_steps, B) slabs —
+                # host memory is bounded by the queue depth, not the dataset.
+                from repro.store import RatingsStore, ShardedRatingsLoader
+
+                if config.epoch_mode != "scan":
+                    raise ValueError("store-backed training requires epoch_mode='scan'")
+                if config.variant == "svdpp":
+                    raise ValueError(
+                        "store-backed training does not support svdpp (the "
+                        "implicit-history matrix is itself O(users))"
+                    )
+                self._store = RatingsStore(config.store_dir)
+                self._loader = ShardedRatingsLoader(
+                    self._store,
+                    config.batch_size,
+                    slab_steps=config.slab_steps,
+                    prefetch=config.prefetch_slabs,
+                )
+            elif train_ds is None:
+                raise ValueError("either train_ds or config.store_dir is required")
+            self.hist = (
+                build_user_history(train_ds, config.max_hist)
+                if config.variant == "svdpp"
                 else None
             )
-            if config.objective == "bpr":
-                from repro.workloads.bpr import BPRSampler
-
-                self._bpr_sampler = BPRSampler(
-                    train_ds, config.batch_size, seed=config.seed
+            if config.epoch_mode == "scan":
+                # Upload the ratings (and eval set / SVD++ history) ONCE;
+                # per-epoch reshuffles happen on device (data/loader.py).  The
+                # batch size is clamped so a tiny dataset trains as one batch
+                # per epoch instead of degenerating to zero steps (which is
+                # what the drop-remainder host loop silently does).  In store
+                # mode the train table never lands on device wholesale.
+                self._packed_train = (
+                    loader.pack_ratings(
+                        train_ds,
+                        min(config.batch_size, max(len(train_ds), 1)),
+                        weight=self._train_weight,
+                    )
+                    if self._loader is None and config.objective != "bpr"
+                    else None
                 )
-            self._packed_eval = (
-                loader.pack_eval_batches(test_ds, config.eval_batch_size)
-                if test_ds is not None
+                if config.objective == "bpr":
+                    from repro.workloads.bpr import BPRSampler
+
+                    self._bpr_sampler = BPRSampler(
+                        train_ds, config.batch_size, seed=config.seed
+                    )
+                self._packed_eval = (
+                    loader.pack_eval_batches(test_ds, config.eval_batch_size)
+                    if test_ds is not None
+                    else None
+                )
+            self._hist_dev = None if self.hist is None else jnp.asarray(self.hist)
+            self._packed_ranking = None
+            if config.ranking_topk > 0 and test_ds is not None:
+                from repro.eval import ranking as ranking_eval
+
+                self._packed_ranking = ranking_eval.pack_ranking_batches(
+                    test_ds, batch_size=256, max_users=config.ranking_max_users
+                )
+
+            rng = jax.random.PRNGKey(config.seed)
+            src = train_ds if train_ds is not None else self._store
+            self.params = mf.init_params(
+                rng,
+                src.num_users,
+                src.num_items,
+                config.k,
+                variant=config.variant,
+                init_method=config.init_method,
+                global_mean=src.global_mean,
+            )
+            self.opt_state = mf.init_opt_state(self.params, self.opt)
+            self.t_p = jnp.float32(0.0)
+            self.t_q = jnp.float32(0.0)
+            self.perm: Optional[jax.Array] = None
+            self.epoch = 0
+            self.history: List[EpochRecord] = []
+            self._ckpt = (
+                ckpt_lib.AsyncCheckpointer(
+                    config.checkpoint_dir, keep=config.keep_checkpoints
+                )
+                if config.checkpoint_dir
                 else None
             )
-        self._hist_dev = None if self.hist is None else jnp.asarray(self.hist)
-        self._packed_ranking = None
-        if config.ranking_topk > 0 and test_ds is not None:
-            from repro.eval import ranking as ranking_eval
-
-            self._packed_ranking = ranking_eval.pack_ranking_batches(
-                test_ds, batch_size=256, max_users=config.ranking_max_users
-            )
-
-        rng = jax.random.PRNGKey(config.seed)
-        src = train_ds if train_ds is not None else self._store
-        self.params = mf.init_params(
-            rng,
-            src.num_users,
-            src.num_items,
-            config.k,
-            variant=config.variant,
-            init_method=config.init_method,
-            global_mean=src.global_mean,
-        )
-        self.opt_state = mf.init_opt_state(self.params, self.opt)
-        self.t_p = jnp.float32(0.0)
-        self.t_q = jnp.float32(0.0)
-        self.perm: Optional[jax.Array] = None
-        self.epoch = 0
-        self.history: List[EpochRecord] = []
-        self._ckpt = (
-            ckpt_lib.AsyncCheckpointer(
-                config.checkpoint_dir, keep=config.keep_checkpoints
-            )
-            if config.checkpoint_dir
-            else None
-        )
 
     # -- checkpoint/restart ------------------------------------------------
     def _state_tree(self) -> Dict[str, Any]:
@@ -333,93 +335,168 @@ class DPMFTrainer:
 
     # -- the paper's one-time calibration (after epoch 1) -------------------
     def calibrate(self) -> None:
-        cfg = self.config
-        if cfg.pruning_rate <= 0.0:
-            return
-        self.t_p, self.t_q = threshold.thresholds_from_matrices(
-            self.params.p, self.params.q, cfg.pruning_rate
-        )
-        if not cfg.rearrange:  # ablation: prune without Algorithm 1
-            self.perm = jnp.arange(cfg.k, dtype=jnp.int32)
-            return
-        result = rearrange.rearrangement(
-            self.params.p, self.params.q, self.t_p, self.t_q
-        )
-        self.perm = result.perm
-        new_p, new_q = rearrange.apply_perm(self.params.p, self.params.q, self.perm)
-        self.params = self.params._replace(p=new_p, q=new_q)
-        if self.params.implicit is not None:
-            self.params = self.params._replace(
-                implicit=jnp.take(self.params.implicit, self.perm, axis=1)
+        with tracing.span("repro.trainer.calibrate"):
+            cfg = self.config
+            if cfg.pruning_rate <= 0.0:
+                return
+            self.t_p, self.t_q = threshold.thresholds_from_matrices(
+                self.params.p, self.params.q, cfg.pruning_rate
             )
-        # Keep optimizer accumulators aligned with the permuted latent axis.
-        def permute_state(state):
-            return {
-                key: (
-                    jnp.take(value, self.perm, axis=1)
-                    if getattr(value, "ndim", 0) == 2
-                    and value.shape[1] == self.config.k
-                    else value
+            if not cfg.rearrange:  # ablation: prune without Algorithm 1
+                self.perm = jnp.arange(cfg.k, dtype=jnp.int32)
+                return
+            result = rearrange.rearrangement(
+                self.params.p, self.params.q, self.t_p, self.t_q
+            )
+            self.perm = result.perm
+            new_p, new_q = rearrange.apply_perm(self.params.p, self.params.q, self.perm)
+            self.params = self.params._replace(p=new_p, q=new_q)
+            if self.params.implicit is not None:
+                self.params = self.params._replace(
+                    implicit=jnp.take(self.params.implicit, self.perm, axis=1)
                 )
-                for key, value in state.items()
-            }
+            # Keep optimizer accumulators aligned with the permuted latent axis.
+            def permute_state(state):
+                return {
+                    key: (
+                        jnp.take(value, self.perm, axis=1)
+                        if getattr(value, "ndim", 0) == 2
+                        and value.shape[1] == self.config.k
+                        else value
+                    )
+                    for key, value in state.items()
+                }
 
-        self.opt_state = self.opt_state._replace(
-            p=permute_state(self.opt_state.p),
-            q=permute_state(self.opt_state.q),
-            implicit=(
-                None
-                if self.opt_state.implicit is None
-                else permute_state(self.opt_state.implicit)
-            ),
-        )
+            self.opt_state = self.opt_state._replace(
+                p=permute_state(self.opt_state.p),
+                q=permute_state(self.opt_state.q),
+                implicit=(
+                    None
+                    if self.opt_state.implicit is None
+                    else permute_state(self.opt_state.implicit)
+                ),
+            )
 
     # -- epochs --------------------------------------------------------------
     def run_epoch(self) -> EpochRecord:
         cfg = self.config
         pruning_active = cfg.pruning_rate > 0.0 and self.epoch >= 1
-        t_p = self.t_p if pruning_active else jnp.float32(0.0)
-        t_q = self.t_q if pruning_active else jnp.float32(0.0)
-        dim_mask = (
-            twin_learners_mask(cfg.k, self.epoch)
-            if cfg.strategy == "twin"
-            else jnp.ones((cfg.k,), jnp.float32)
-        )
-        lr = jnp.float32(cfg.lr)
+        with tracing.span(
+            "repro.trainer.epoch", epoch=self.epoch, pruned=int(pruning_active)
+        ):
+            t_p = self.t_p if pruning_active else jnp.float32(0.0)
+            t_q = self.t_q if pruning_active else jnp.float32(0.0)
+            dim_mask = (
+                twin_learners_mask(cfg.k, self.epoch)
+                if cfg.strategy == "twin"
+                else jnp.ones((cfg.k,), jnp.float32)
+            )
+            lr = jnp.float32(cfg.lr)
 
-        start = time.perf_counter()
-        straggler_slabs = 0
-        retry_count = [0]
-        if self._loader is not None:
-            # Store mode: the epoch is a sequence of slab-chunked scans fed
-            # by the prefetch queue.  Metric means accumulate step-weighted
-            # in host float64 so a mid-epoch resume (which restores the
-            # partial sums from metadata) reports bitwise-identical epoch
-            # numbers to an uninterrupted run — both execute this same
-            # chunked path over the same deterministic slab order.
-            err_sum, work_sum, steps_done = self._resume_sums
-            start_slab = self._resume_slab
-            self._resume_slab = 0
-            self._resume_sums = (0.0, 0.0, 0)
-            num_slabs = self._loader.num_slabs
-            for slab in self._loader.epoch_slabs(
-                cfg.seed, self.epoch, start_slab=start_slab
-            ):
-                def run_slab(slab=slab):
-                    # faults fire BEFORE the dispatch so a retry re-runs
-                    # the slab against untouched params (no donation hazard)
-                    if self.failure_injector is not None:
-                        self.failure_injector(self._slab_counter)
-                    if faults._PLAN is not None:
-                        for act in faults.fire("trainer.slab"):
-                            if act.op == "error":
-                                raise faults.FaultError(
-                                    "injected slab failure"
-                                )
-                    return mf.train_epoch_scan(
+            start = time.perf_counter()
+            straggler_slabs = 0
+            retry_count = [0]
+            if self._loader is not None:
+                # Store mode: the epoch is a sequence of slab-chunked scans fed
+                # by the prefetch queue.  Metric means accumulate step-weighted
+                # in host float64 so a mid-epoch resume (which restores the
+                # partial sums from metadata) reports bitwise-identical epoch
+                # numbers to an uninterrupted run — both execute this same
+                # chunked path over the same deterministic slab order.
+                err_sum, work_sum, steps_done = self._resume_sums
+                start_slab = self._resume_slab
+                self._resume_slab = 0
+                self._resume_sums = (0.0, 0.0, 0)
+                num_slabs = self._loader.num_slabs
+                for slab in self._loader.epoch_slabs(
+                    cfg.seed, self.epoch, start_slab=start_slab
+                ):
+                    def run_slab(slab=slab):
+                        # faults fire BEFORE the dispatch so a retry re-runs
+                        # the slab against untouched params (no donation hazard)
+                        if self.failure_injector is not None:
+                            self.failure_injector(self._slab_counter)
+                        if faults._PLAN is not None:
+                            for act in faults.fire("trainer.slab"):
+                                if act.op == "error":
+                                    raise faults.FaultError(
+                                        "injected slab failure"
+                                    )
+                        return mf.train_epoch_scan(
+                            self.params,
+                            self.opt_state,
+                            slab.batches,
+                            t_p,
+                            t_q,
+                            lr,
+                            dim_mask,
+                            self._hist_dev,
+                            opt=self.opt,
+                            lam=cfg.lam,
+                            use_fused_kernel=cfg.use_fused_kernel,
+                        )
+
+                    slab_start = time.perf_counter()
+                    if cfg.max_step_retries > 0:
+                        self.params, self.opt_state, metrics = run_with_retries(
+                            run_slab,
+                            max_retries=cfg.max_step_retries,
+                            backoff_s=0.05,
+                            on_retry=lambda n, exc: retry_count.__setitem__(
+                                0, retry_count[0] + 1
+                            ),
+                        )
+                    else:
+                        self.params, self.opt_state, metrics = run_slab()
+                    jax.block_until_ready(self.params.p)
+                    if self.straggler.record(time.perf_counter() - slab_start):
+                        straggler_slabs += 1
+                    self._slab_counter += 1
+                    err_sum += float(metrics["abs_err"]) * slab.steps
+                    work_sum += float(metrics["work_fraction"]) * slab.steps
+                    steps_done += slab.steps
+                    slabs_done = slab.slab_idx + 1
+                    if (
+                        self._ckpt is not None
+                        and cfg.checkpoint_every_slabs
+                        and slabs_done % cfg.checkpoint_every_slabs == 0
+                        and slabs_done < num_slabs
+                    ):
+                        self._save_mid_epoch(slabs_done, err_sum, work_sum, steps_done)
+                abs_err = err_sum / max(steps_done, 1)
+                work = work_sum / max(steps_done, 1)
+            elif cfg.objective == "bpr":
+                # Pairwise epoch: freshly sampled (user, pos, neg) triples folded
+                # through the same scan machinery; abs_err carries the BPR loss.
+                from repro.workloads import bpr as bpr_wl
+
+                triples = self._bpr_sampler.epoch_triples(self.epoch)
+                self.params, self.opt_state, metrics = bpr_wl.bpr_epoch_scan(
+                    self.params,
+                    self.opt_state,
+                    triples,
+                    t_p,
+                    t_q,
+                    lr,
+                    dim_mask,
+                    opt=self.opt,
+                    lam=cfg.lam,
+                )
+                jax.block_until_ready(self.params.p)
+                abs_err = float(metrics["abs_err"])
+                work = float(metrics["work_fraction"])
+            elif cfg.epoch_mode == "scan":
+                # One donated, compiled computation for the whole epoch: on-device
+                # reshuffle, lax.scan of train_step, metrics summed on device.
+                with tracing.span("repro.trainer.shuffle"):
+                    batches = self._packed_train.epoch_batches(
+                        cfg.seed, self.epoch
+                    )
+                with tracing.span("repro.trainer.step"):
+                    self.params, self.opt_state, metrics = mf.train_epoch_scan(
                         self.params,
                         self.opt_state,
-                        slab.batches,
+                        batches,
                         t_p,
                         t_q,
                         lr,
@@ -429,142 +506,80 @@ class DPMFTrainer:
                         lam=cfg.lam,
                         use_fused_kernel=cfg.use_fused_kernel,
                     )
-
-                slab_start = time.perf_counter()
-                if cfg.max_step_retries > 0:
-                    self.params, self.opt_state, metrics = run_with_retries(
-                        run_slab,
-                        max_retries=cfg.max_step_retries,
-                        backoff_s=0.05,
-                        on_retry=lambda n, exc: retry_count.__setitem__(
-                            0, retry_count[0] + 1
-                        ),
-                    )
-                else:
-                    self.params, self.opt_state, metrics = run_slab()
-                jax.block_until_ready(self.params.p)
-                if self.straggler.record(time.perf_counter() - slab_start):
-                    straggler_slabs += 1
-                self._slab_counter += 1
-                err_sum += float(metrics["abs_err"]) * slab.steps
-                work_sum += float(metrics["work_fraction"]) * slab.steps
-                steps_done += slab.steps
-                slabs_done = slab.slab_idx + 1
-                if (
-                    self._ckpt is not None
-                    and cfg.checkpoint_every_slabs
-                    and slabs_done % cfg.checkpoint_every_slabs == 0
-                    and slabs_done < num_slabs
+                with tracing.span("repro.trainer.sync"):
+                    jax.block_until_ready(self.params.p)
+                    # the epoch's single host sync: two scalars
+                    abs_err = float(metrics["abs_err"])
+                    work = float(metrics["work_fraction"])
+            else:
+                # Legacy per-batch loop.  Metrics accumulate as device scalars —
+                # fetched once after the loop, never per step (a float() here
+                # would serialize every dispatch on a host sync).
+                abs_err_sum = jnp.zeros((), jnp.float32)
+                work_sum = jnp.zeros((), jnp.float32)
+                steps = 0
+                for batch_np in loader.iterate_batches(
+                    self.train_ds,
+                    cfg.batch_size,
+                    seed=cfg.seed,
+                    epoch=self.epoch,
+                    hist=self.hist,
                 ):
-                    self._save_mid_epoch(slabs_done, err_sum, work_sum, steps_done)
-            abs_err = err_sum / max(steps_done, 1)
-            work = work_sum / max(steps_done, 1)
-        elif cfg.objective == "bpr":
-            # Pairwise epoch: freshly sampled (user, pos, neg) triples folded
-            # through the same scan machinery; abs_err carries the BPR loss.
-            from repro.workloads import bpr as bpr_wl
+                    batch = {key: jnp.asarray(value) for key, value in batch_np.items()}
+                    self.params, self.opt_state, metrics = mf.train_step(
+                        self.params,
+                        self.opt_state,
+                        batch,
+                        t_p,
+                        t_q,
+                        lr,
+                        dim_mask,
+                        opt=self.opt,
+                        lam=cfg.lam,
+                        use_fused_kernel=cfg.use_fused_kernel,
+                    )
+                    abs_err_sum = abs_err_sum + metrics["abs_err"]
+                    work_sum = work_sum + metrics["work_fraction"]
+                    steps += 1
+                jax.block_until_ready(self.params.p)
+                abs_err = float(abs_err_sum) / max(steps, 1)
+                work = float(work_sum) / max(steps, 1)
+            wall = time.perf_counter() - start
 
-            triples = self._bpr_sampler.epoch_triples(self.epoch)
-            self.params, self.opt_state, metrics = bpr_wl.bpr_epoch_scan(
-                self.params,
-                self.opt_state,
-                triples,
-                t_p,
-                t_q,
-                lr,
-                dim_mask,
-                opt=self.opt,
-                lam=cfg.lam,
-            )
-            jax.block_until_ready(self.params.p)
-            abs_err = float(metrics["abs_err"])
-            work = float(metrics["work_fraction"])
-        elif cfg.epoch_mode == "scan":
-            # One donated, compiled computation for the whole epoch: on-device
-            # reshuffle, lax.scan of train_step, metrics summed on device.
-            batches = self._packed_train.epoch_batches(cfg.seed, self.epoch)
-            self.params, self.opt_state, metrics = mf.train_epoch_scan(
-                self.params,
-                self.opt_state,
-                batches,
-                t_p,
-                t_q,
-                lr,
-                dim_mask,
-                self._hist_dev,
-                opt=self.opt,
-                lam=cfg.lam,
-                use_fused_kernel=cfg.use_fused_kernel,
-            )
-            jax.block_until_ready(self.params.p)
-            # the epoch's single host sync: two scalars
-            abs_err = float(metrics["abs_err"])
-            work = float(metrics["work_fraction"])
-        else:
-            # Legacy per-batch loop.  Metrics accumulate as device scalars —
-            # fetched once after the loop, never per step (a float() here
-            # would serialize every dispatch on a host sync).
-            abs_err_sum = jnp.zeros((), jnp.float32)
-            work_sum = jnp.zeros((), jnp.float32)
-            steps = 0
-            for batch_np in loader.iterate_batches(
-                self.train_ds,
-                cfg.batch_size,
-                seed=cfg.seed,
-                epoch=self.epoch,
-                hist=self.hist,
-            ):
-                batch = {key: jnp.asarray(value) for key, value in batch_np.items()}
-                self.params, self.opt_state, metrics = mf.train_step(
-                    self.params,
-                    self.opt_state,
-                    batch,
-                    t_p,
-                    t_q,
-                    lr,
-                    dim_mask,
-                    opt=self.opt,
-                    lam=cfg.lam,
-                    use_fused_kernel=cfg.use_fused_kernel,
+            with tracing.span("repro.trainer.evaluate"):
+                test_mae = (
+                    self.evaluate(t_p, t_q)
+                    if self.test_ds is not None else float("nan")
                 )
-                abs_err_sum = abs_err_sum + metrics["abs_err"]
-                work_sum = work_sum + metrics["work_fraction"]
-                steps += 1
-            jax.block_until_ready(self.params.p)
-            abs_err = float(abs_err_sum) / max(steps, 1)
-            work = float(work_sum) / max(steps, 1)
-        wall = time.perf_counter() - start
+                ranking = self.evaluate_ranking(t_p, t_q)
+            record = EpochRecord(
+                epoch=self.epoch,
+                wall_time_s=wall,
+                train_abs_err=abs_err,
+                test_mae=test_mae,
+                work_fraction=work,
+                t_p=float(t_p),
+                t_q=float(t_q),
+                straggler_slabs=straggler_slabs,
+                step_retries=retry_count[0],
+                **(
+                    {"hr": ranking.hr, "ndcg": ranking.ndcg,
+                     "recall": ranking.recall}
+                    if ranking is not None else {}
+                ),
+            )
+            self.history.append(record)
 
-        test_mae = self.evaluate(t_p, t_q) if self.test_ds is not None else float("nan")
-        ranking = self.evaluate_ranking(t_p, t_q)
-        record = EpochRecord(
-            epoch=self.epoch,
-            wall_time_s=wall,
-            train_abs_err=abs_err,
-            test_mae=test_mae,
-            work_fraction=work,
-            t_p=float(t_p),
-            t_q=float(t_q),
-            straggler_slabs=straggler_slabs,
-            step_retries=retry_count[0],
-            **(
-                {"hr": ranking.hr, "ndcg": ranking.ndcg,
-                 "recall": ranking.recall}
-                if ranking is not None else {}
-            ),
-        )
-        self.history.append(record)
-
-        if self.epoch == 0:
-            self.calibrate()  # paper: once, right after the first epoch
-        self.epoch += 1
-        if (
-            self._ckpt is not None
-            and cfg.checkpoint_every_epochs
-            and self.epoch % cfg.checkpoint_every_epochs == 0
-        ):
-            self.save(self._ckpt_step())
-        return record
+            if self.epoch == 0:
+                self.calibrate()  # paper: once, right after the first epoch
+            self.epoch += 1
+            if (
+                self._ckpt is not None
+                and cfg.checkpoint_every_epochs
+                and self.epoch % cfg.checkpoint_every_epochs == 0
+            ):
+                self.save(self._ckpt_step())
+            return record
 
     def run(self) -> List[EpochRecord]:
         start_epoch = self.epoch

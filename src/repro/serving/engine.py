@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.checkpoint import checkpoint as ckpt_lib
 from repro.core import mf
 from repro.core.ranks import effective_ranks, rank_mask
@@ -921,16 +922,21 @@ class ServingEngine:
         out_i = np.empty((len(ids), topk), np.int32)
         for lo in range(0, len(ids), self.max_batch):
             chunk = ids[lo : lo + self.max_batch]
-            bucket = bucket_size(len(chunk), self.max_batch)
-            padded = np.pad(chunk, (0, bucket - len(chunk)), mode="edge")
-            pu = self._user_vectors(snap, padded)
-            scores, idx = block_fn(pu, topk)
-            scores = np.asarray(scores[: len(chunk)])
-            idx = np.asarray(idx[: len(chunk)])
-            if snap.user_const is not None:
-                scores = scores + snap.user_const[chunk][:, None]
-            out_s[lo : lo + len(chunk)] = scores
-            out_i[lo : lo + len(chunk)] = idx
+            with tracing.span("repro.serving.gather"):
+                bucket = bucket_size(len(chunk), self.max_batch)
+                padded = np.pad(chunk, (0, bucket - len(chunk)), mode="edge")
+                pu = self._user_vectors(snap, padded)
+            with tracing.span(
+                "repro.serving.launch", users=len(chunk), bucket=bucket
+            ):
+                scores, idx = block_fn(pu, topk)
+            with tracing.span("repro.serving.fetch"):
+                scores = np.asarray(scores[: len(chunk)])
+                idx = np.asarray(idx[: len(chunk)])
+                if snap.user_const is not None:
+                    scores = scores + snap.user_const[chunk][:, None]
+                out_s[lo : lo + len(chunk)] = scores
+                out_i[lo : lo + len(chunk)] = idx
         return out_s, out_i
 
     def topk(
@@ -940,14 +946,15 @@ class ServingEngine:
         as (B, topk) numpy arrays — the ``jax.lax.top_k`` ordering, same as
         ``kernels.ops.pruned_topk`` and ``ref.pruned_topk_ref`` — identical
         to dense score-and-argsort."""
-        snap = self._snap  # captured once: the whole batch serves one version
-        ids = self._validate_for(snap, user_ids, topk)
-        phys, evicted = self._translate_ids(snap, ids)
-        out_s, out_i = self._run_chunked(
-            snap, phys, topk,
-            lambda pu, k_: self._topk_block(snap, pu, k_),
-        )
-        return self._apply_fallback(snap, evicted, topk, out_s, out_i)
+        with tracing.span("repro.serving.topk", users=np.size(user_ids)):
+            snap = self._snap  # captured once: the whole batch serves one version
+            ids = self._validate_for(snap, user_ids, topk)
+            phys, evicted = self._translate_ids(snap, ids)
+            out_s, out_i = self._run_chunked(
+                snap, phys, topk,
+                lambda pu, k_: self._topk_block(snap, pu, k_),
+            )
+            return self._apply_fallback(snap, evicted, topk, out_s, out_i)
 
     # -- sharded catalog -----------------------------------------------------
     def _sharded_program(self, mesh, topk: int, kernel: bool):
