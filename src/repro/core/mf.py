@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from repro.core.ranks import effective_ranks, rank_mask
 from repro.kernels import ops as kops
-from repro.optim.optimizers import RowOptimizer
+from repro.optim.optimizers import RowOptimizer, add_rows, rows_written
 
 Batch = Dict[str, jax.Array]
 
@@ -218,7 +218,8 @@ def _train_step(
     Pallas kernel (biases and the weight column ride along in-kernel); every
     other (variant, optimizer) combination uses the masked XLA formulation
     with identical semantics.  Duplicate (u, i) rows in a batch accumulate
-    additively (scatter-add), the standard minibatch relaxation of the
+    additively (summed per row on the device and written once, see
+    ``optim.optimizers.add_rows``), the standard minibatch relaxation of the
     paper's sequential SGD.
 
     An optional ``batch["weight"]`` (B,) gates rows out of the update —
@@ -246,6 +247,13 @@ def _train_step(
         jnp.ones_like(r) if weight is None else weight.astype(jnp.float32)
     )
     mask = pred_mask * w[:, None]  # gates updates; predictions use pred_mask
+    # an occurrence with an all-zero mask adds exact zeros: no row is written
+    # for it, and the counters leave it out (the same sort as the writes)
+    live = jnp.any(mask != 0, axis=-1)
+    rows = (
+        rows_written(u, live, params.p.shape[0]) / u.shape[0],
+        rows_written(i, live, params.q.shape[0]) / i.shape[0],
+    )
 
     fused_ok = (
         use_fused_kernel
@@ -269,31 +277,19 @@ def _train_step(
             interpret=interpret,
         )
         # kernel computed rows at lr=1; rescale the delta by the traced lr and
-        # the strategy mask, then scatter-add (duplicate-safe).
+        # the strategy mask, then add each side's rows in one write.
         dp = (new_pu - params.p[u]) * lr * dim_mask[None, :]
         dq = (new_qi - qi) * lr * dim_mask[None, :]
-        new_params = params._replace(
-            p=params.p.at[u].add(dp.astype(params.p.dtype)),
-            q=params.q.at[i].add(dq.astype(params.q.dtype)),
-        )
+        (new_p,) = add_rows((params.p,), u, (dp,), live)
+        (new_q,) = add_rows((params.q,), i, (dq,), live)
+        new_params = params._replace(p=new_p, q=new_q)
         if has_bias:
             dbu = (new_bu - params.user_bias[u, 0]) * lr
             dbi = (new_bi - params.item_bias[i, 0]) * lr
-            new_params = new_params._replace(
-                user_bias=params.user_bias.at[u, 0].add(
-                    dbu.astype(params.user_bias.dtype)
-                ),
-                item_bias=params.item_bias.at[i, 0].add(
-                    dbi.astype(params.item_bias.dtype)
-                ),
-            )
-        denom = jnp.maximum(jnp.sum(w), 1e-9)
-        metrics = {
-            "abs_err": jnp.sum(jnp.abs(err) * w) / denom,
-            "work_fraction": jnp.sum(pair_ranks.astype(jnp.float32) * w)
-            / (denom * k),
-        }
-        return new_params, opt_state, metrics
+            (new_bu,) = add_rows((params.user_bias,), u, (dbu[:, None],), w != 0)
+            (new_bi,) = add_rows((params.item_bias,), i, (dbi[:, None],), w != 0)
+            new_params = new_params._replace(user_bias=new_bu, item_bias=new_bi)
+        return new_params, opt_state, _step_metrics(err, w, pair_ranks, k, rows)
 
     pred = jnp.sum(
         pu.astype(jnp.float32) * qi.astype(jnp.float32) * pred_mask, axis=-1
@@ -353,13 +349,20 @@ def _train_step(
         new_params = new_params._replace(implicit=new_y)
         new_state = new_state._replace(implicit=st_y)
 
+    return new_params, new_state, _step_metrics(err, w, pair_ranks, k, rows)
+
+
+def _step_metrics(err, w, pair_ranks, k, rows) -> Dict[str, jax.Array]:
+    """A step's weighted means, and the share of the batch's ids that are
+    rows written, user side and item side."""
     denom = jnp.maximum(jnp.sum(w), 1e-9)  # weighted mean, not deflated
-    metrics = {
+    return {
         "abs_err": jnp.sum(jnp.abs(err) * w) / denom,
         "work_fraction": jnp.sum(pair_ranks.astype(jnp.float32) * w)
         / (denom * k),
+        "user_rows_share": rows[0],
+        "item_rows_share": rows[1],
     }
-    return new_params, new_state, metrics
 
 
 train_step = jax.jit(
@@ -401,23 +404,25 @@ def _epoch_scan(step_fn, params, opt_state, batches):
     """
     steps = jax.tree_util.tree_leaves(batches)[0].shape[0]
 
-    def body(carry, batch):
-        p, s, err_sum, work_sum = carry
-        p, s, m = step_fn(p, s, batch)
-        return (p, s, err_sum + m["abs_err"], work_sum + m["work_fraction"]), None
-
-    init = (
-        params,
-        opt_state,
-        jnp.zeros((), jnp.float32),
-        jnp.zeros((), jnp.float32),
+    sums0 = jax.tree.map(
+        lambda m: jnp.zeros(m.shape, m.dtype),
+        jax.eval_shape(
+            lambda: step_fn(
+                params, opt_state, jax.tree.map(lambda b: b[0], batches)
+            )[2]
+        ),
     )
-    (new_params, new_state, err_sum, work_sum), _ = jax.lax.scan(
-        body, init, batches
+
+    def body(carry, batch):
+        p, s, sums = carry
+        p, s, m = step_fn(p, s, batch)
+        return (p, s, {key: sums[key] + m[key] for key in sums}), None
+
+    (new_params, new_state, sums), _ = jax.lax.scan(
+        body, (params, opt_state, sums0), batches
     )
     denom = jnp.float32(max(steps, 1))
-    metrics = {"abs_err": err_sum / denom, "work_fraction": work_sum / denom}
-    return new_params, new_state, metrics
+    return new_params, new_state, {key: v / denom for key, v in sums.items()}
 
 
 @functools.partial(
@@ -726,6 +731,13 @@ def train_step_shard_map(
         )
         err = r.astype(jnp.float32) - pred
         wv = w.astype(jnp.float32)[:, None]
+        # train_step's live occurrences (mask = pair mask x weight), read on
+        # each item's owner: the rows written, identical on every rank
+        live = jax.lax.psum(
+            jnp.any(own * pair_mask * wv != 0, axis=-1).astype(jnp.int32),
+            "model",
+        ) > 0
+        live_p, live_q = live, live & is_local
 
         # p gradient: assembled on the item owner (it holds q), then one psum.
         # Both gradients carry the full pair mask (Alg. 3 truncates the
@@ -743,6 +755,7 @@ def train_step_shard_map(
             # duplicate batch rows stay deterministic; the old residual rides
             # on one live occurrence of the row only.
             carrier = _first_occurrence(jnp.where(wv[:, 0] > 0, u_loc, m_loc))
+            live_p = live_p | carrier   # a carried residual is an update too
             resid = ef_p[u_loc] * carrier[:, None]
             target = g_p_partial + resid
             local_max = jnp.max(jnp.abs(target))
@@ -771,7 +784,9 @@ def train_step_shard_map(
             # pair-mask part of that second mask is already folded into g.)
             acc_p_rows = acc_p[u_loc] + g_p * g_p
             dp_rows = -lr * g_p / jnp.sqrt(acc_p_rows + eps) * wv
-            acc_p = acc_p.at[u_loc].add(g_p * g_p)
+            p_blk, acc_p = add_rows(
+                (p_blk, acc_p), u_loc, (dp_rows, g_p * g_p), live_p
+            )
             acc_q_rows = acc_q[safe_i] + g_q * g_q
             dq_rows = jnp.where(
                 is_local[:, None],
@@ -781,8 +796,7 @@ def train_step_shard_map(
         else:  # plain SGD
             dp_rows = -lr * g_p
             dq_rows = -lr * g_q
-
-        p_blk = p_blk.at[u_loc].add(dp_rows.astype(p_blk.dtype))
+            (p_blk,) = add_rows((p_blk,), u_loc, (dp_rows,), live_p)
 
         # Q is replicated along the data axes, but each data shard computed
         # deltas only for ITS ratings: all-gather the sparse (B_loc, k) delta
@@ -803,6 +817,7 @@ def train_step_shard_map(
                     carrier = _first_occurrence(
                         jnp.where(is_local & (w > 0), safe_i, n_loc)
                     )
+                    live_q = live_q | (carrier & is_local)
                     payload = jnp.where(
                         is_local[:, None],
                         dq_rows + ef_q[safe_i] * carrier[:, None],
@@ -824,14 +839,20 @@ def train_step_shard_map(
             else:
                 gat_dq = jax.lax.all_gather(dq_rows, dp).reshape(-1, k)
             gat_idx = jax.lax.all_gather(safe_i, dp).reshape(-1)
-            q_blk = q_blk.at[gat_idx].add(gat_dq.astype(q_blk.dtype))
+            gat_live = jax.lax.all_gather(live_q, dp).reshape(-1)
             if adagrad:
                 gat_g2 = jax.lax.all_gather(g_q * g_q, dp).reshape(-1, k)
-                acc_q = acc_q.at[gat_idx].add(gat_g2)
+                q_blk, acc_q = add_rows(
+                    (q_blk, acc_q), gat_idx, (gat_dq, gat_g2), gat_live
+                )
+            else:
+                (q_blk,) = add_rows((q_blk,), gat_idx, (gat_dq,), gat_live)
+        elif adagrad:
+            q_blk, acc_q = add_rows(
+                (q_blk, acc_q), safe_i, (dq_rows, g_q * g_q), live_q
+            )
         else:
-            q_blk = q_blk.at[safe_i].add(dq_rows.astype(q_blk.dtype))
-            if adagrad:
-                acc_q = acc_q.at[safe_i].add(g_q * g_q)
+            (q_blk,) = add_rows((q_blk,), safe_i, (dq_rows,), live_q)
 
         # Weighted epoch metrics, summed on device (err and w are identical
         # on every model rank, so only the data axes need a psum).

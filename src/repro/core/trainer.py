@@ -106,6 +106,10 @@ class EpochRecord:
     recall: float = float("nan")   # recall@K
     straggler_slabs: int = 0       # slabs flagged as wall-time outliers
     step_retries: int = 0          # slab retries consumed this epoch
+    # mean share of a step's ids that are distinct rows of P / Q written
+    # (NaN on the store path, which logs only the error and the work)
+    user_rows_share: float = float("nan")
+    item_rows_share: float = float("nan")
 
 
 class DPMFTrainer:
@@ -463,8 +467,10 @@ class DPMFTrainer:
                         and slabs_done < num_slabs
                     ):
                         self._save_mid_epoch(slabs_done, err_sum, work_sum, steps_done)
-                abs_err = err_sum / max(steps_done, 1)
-                work = work_sum / max(steps_done, 1)
+                epoch_metrics = {
+                    "abs_err": err_sum / max(steps_done, 1),
+                    "work_fraction": work_sum / max(steps_done, 1),
+                }
             elif cfg.objective == "bpr":
                 # Pairwise epoch: freshly sampled (user, pos, neg) triples folded
                 # through the same scan machinery; abs_err carries the BPR loss.
@@ -483,8 +489,7 @@ class DPMFTrainer:
                     lam=cfg.lam,
                 )
                 jax.block_until_ready(self.params.p)
-                abs_err = float(metrics["abs_err"])
-                work = float(metrics["work_fraction"])
+                epoch_metrics = jax.device_get(metrics)
             elif cfg.epoch_mode == "scan":
                 # One donated, compiled computation for the whole epoch: on-device
                 # reshuffle, lax.scan of train_step, metrics summed on device.
@@ -508,15 +513,16 @@ class DPMFTrainer:
                     )
                 with tracing.span("repro.trainer.sync"):
                     jax.block_until_ready(self.params.p)
-                    # the epoch's single host sync: two scalars
-                    abs_err = float(metrics["abs_err"])
-                    work = float(metrics["work_fraction"])
+                    # the epoch's single host sync: a few scalars
+                    epoch_metrics = jax.device_get(metrics)
             else:
                 # Legacy per-batch loop.  Metrics accumulate as device scalars —
                 # fetched once after the loop, never per step (a float() here
                 # would serialize every dispatch on a host sync).
-                abs_err_sum = jnp.zeros((), jnp.float32)
-                work_sum = jnp.zeros((), jnp.float32)
+                metric_sums = {
+                    "abs_err": jnp.zeros((), jnp.float32),
+                    "work_fraction": jnp.zeros((), jnp.float32),
+                }
                 steps = 0
                 for batch_np in loader.iterate_batches(
                     self.train_ds,
@@ -538,12 +544,23 @@ class DPMFTrainer:
                         lam=cfg.lam,
                         use_fused_kernel=cfg.use_fused_kernel,
                     )
-                    abs_err_sum = abs_err_sum + metrics["abs_err"]
-                    work_sum = work_sum + metrics["work_fraction"]
+                    metric_sums = {
+                        key: metric_sums.get(key, 0.0) + value
+                        for key, value in metrics.items()
+                    }
                     steps += 1
                 jax.block_until_ready(self.params.p)
-                abs_err = float(abs_err_sum) / max(steps, 1)
-                work = float(work_sum) / max(steps, 1)
+                epoch_metrics = {
+                    key: float(value) / max(steps, 1)
+                    for key, value in jax.device_get(metric_sums).items()
+                }
+            abs_err = float(epoch_metrics["abs_err"])
+            work = float(epoch_metrics["work_fraction"])
+            rows_shares = {
+                key: float(epoch_metrics[key])
+                for key in ("user_rows_share", "item_rows_share")
+                if key in epoch_metrics
+            }
             wall = time.perf_counter() - start
 
             with tracing.span("repro.trainer.evaluate"):
@@ -562,6 +579,7 @@ class DPMFTrainer:
                 t_q=float(t_q),
                 straggler_slabs=straggler_slabs,
                 step_retries=retry_count[0],
+                **rows_shares,
                 **(
                     {"hr": ranking.hr, "ndcg": ranking.ndcg,
                      "recall": ranking.recall}

@@ -3,11 +3,19 @@
 Two families:
 
 * **Row optimizers** — the MF/embedding path.  State lives alongside the
-  (rows, k) table; updates touch only gathered rows and are scattered back
-  with duplicate-safe ``.at[].add``.  All of them accept the paper's pruning
-  ``mask`` so Algorithm 3's truncated update composes with any optimizer
-  (paper §5.3 shows the method is optimizer-agnostic; we implement SGD,
-  momentum, Adagrad — LibMF's default — AdaDelta and Adam).
+  (rows, k) table; updates touch only gathered rows.  All of them accept the
+  paper's pruning ``mask`` so Algorithm 3's truncated update composes with
+  any optimizer (paper §5.3 shows the method is optimizer-agnostic; we
+  implement SGD, momentum, Adagrad — LibMF's default — AdaDelta and Adam).
+  SGD and Adagrad, whose every write is additive, go through
+  :func:`add_rows`: the batch's occurrences are sorted by row id, the
+  updates of each id are summed on the device by a segmented scan, and
+  each touched row is written once, into every table of that side, by a
+  kernel that only streams row DMAs (``kernels/row_write.py``).
+  Duplicates are summed there, in the scan's float32 order, where XLA's
+  scatter on the TPU would apply them one update row at a time.
+  Momentum, AdaDelta and Adam keep their per-occurrence scatters
+  (``.at[].add`` for the rows, last-write ``.at[].set`` for the state).
 * **Dense optimizers** — pytree-wide Adam/SGD for the non-MF architectures
   (transformers, GNN, recsys MLPs).
 
@@ -18,6 +26,7 @@ optimizer state untouched.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Any, Dict, Tuple
 
 import jax
@@ -29,6 +38,104 @@ Pytree = Any
 # ---------------------------------------------------------------------------
 # Row optimizers (embedding tables / factor matrices)
 # ---------------------------------------------------------------------------
+
+
+def _row_runs(idx: jax.Array, live: jax.Array, num_rows: int):
+    """The batch's occurrences in row-id order, and its runs of one id.
+
+    Returns ``(order, rows, head)``: ``order`` sorts the occurrences stably
+    by id, with those not ``live`` last under the key ``num_rows``;
+    ``head`` marks each run's first position, and every occurrence that is
+    not live is a run of its own; ``rows`` holds a run's id at its last
+    position and a distinct id past the table everywhere else, so a write
+    at ``rows`` touches each live row once and skips the rest.
+    """
+    size = idx.shape[0]
+    pos = jnp.arange(size, dtype=jnp.int32)
+    key = jnp.where(live, idx.astype(jnp.int32), num_rows)
+    key, order = jax.lax.sort((key, pos), num_keys=1, is_stable=True)
+    change = key[1:] != key[:-1]
+    inside = key < num_rows
+    head = jnp.concatenate([jnp.ones((1,), bool), change]) | ~inside
+    tail = jnp.concatenate([change, jnp.ones((1,), bool)]) & inside
+    return order, jnp.where(tail, key, num_rows + pos), head
+
+
+def _run_sums(x: jax.Array, head: jax.Array) -> jax.Array:
+    """Inclusive sums of ``x`` (B, w) along each run that ``head`` starts.
+
+    Hillis-Steele doubling: at distance ``d`` a position adds the partial
+    sum ``d`` back when that position lies in its own run.  A run's last
+    position ends up holding the sum of the whole run; a run of one keeps
+    its value bit for bit.  The doubling stops at the longest run, which
+    on the user side of a batch is a few occurrences."""
+    size = x.shape[0]
+    pos = jnp.arange(size, dtype=jnp.int32)
+    start = jax.lax.cummax(jnp.where(head, pos, 0))
+    longest = jnp.max(pos - start) + 1
+
+    def level(carry):
+        x, d = carry
+        same = (pos >= d) & (jnp.roll(start, d) == start)
+        return jnp.where(same[:, None], x + jnp.roll(x, d, axis=0), x), 2 * d
+
+    x, _ = jax.lax.while_loop(
+        lambda carry: carry[1] < longest, level, (x, jnp.int32(1))
+    )
+    return x
+
+
+def rows_written(idx: jax.Array, live: jax.Array, num_rows: int) -> jax.Array:
+    """How many distinct rows :func:`add_rows` writes for ``idx``/``live``.
+
+    Built from the same sort as the write, so in one program XLA computes
+    it once for both."""
+    _, rows, _ = _row_runs(idx, live, num_rows)
+    return jnp.sum(rows < num_rows)
+
+
+def add_rows(
+    tables: Tuple[jax.Array, ...],
+    idx: jax.Array,                   # (B,) row ids, duplicates allowed
+    updates: Tuple[jax.Array, ...],   # per table, (B, width) per occurrence
+    live: jax.Array,                  # (B,) bool; False = adds nothing
+) -> Tuple[jax.Array, ...]:
+    """``tables[t][idx[b]] += updates[t][b]`` for every occurrence ``b``,
+    writing each touched row of each table once.
+
+    One stable sort of the ids serves every table.  A segmented scan sums
+    each run of one id (a prefix sum with a difference would cancel in
+    float32, and ``segment_sum`` is itself a conflicting scatter); the row
+    as it was before the batch, read per occurrence, plus its run's sum is
+    written once by ``kernels/row_write.py``, one row DMA per touched row
+    and table.  Only the float32 order in which duplicates are summed
+    differs from sequential adds.  Occurrences that are not ``live`` (their
+    updates are exact zeros) are left out, so adding or removing them
+    changes no bit.
+    """
+    # imported here: the kernels package imports this module via core.mf
+    from repro.kernels.row_write import write_rows
+
+    num_rows, n = tables[0].shape[0], len(tables)
+    order, rows, head = _row_runs(idx, live, num_rows)
+    cuts = list(itertools.accumulate([t.shape[-1] for t in tables] * 2))[:-1]
+    # one row permutation puts every occurrence's updates, and its row as it
+    # was before the batch, in id order
+    moved = jnp.split(
+        jnp.concatenate(
+            [u.astype(t.dtype) for u, t in zip(updates, tables)]
+            + [t[idx] for t in tables],
+            axis=-1,
+        )[order],
+        cuts,
+        axis=-1,
+    )
+    sums = jnp.split(
+        _run_sums(jnp.concatenate(moved[:n], axis=-1), head), cuts[: n - 1], axis=-1
+    )
+    return write_rows(
+        rows, tuple(cur + s for cur, s in zip(moved[n:], sums)), tuple(tables)
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,8 +173,10 @@ class RowOptimizer:
         lr: float | jax.Array,
     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         g = grad_rows.astype(jnp.float32) * mask
+        live = jnp.any(mask != 0, axis=-1)
         if self.name == "sgd":
-            return param.at[idx].add((-lr * g).astype(param.dtype)), state
+            (param,) = add_rows((param,), idx, ((-lr * g).astype(param.dtype),), live)
+            return param, state
 
         if self.name == "momentum":
             # Heavy ball on the masked gradient.  Like adadelta/adam,
@@ -81,12 +190,13 @@ class RowOptimizer:
             )
 
         if self.name == "adagrad":
+            # each occurrence's delta reads the pre-batch accumulator
             acc_rows = state["acc"][idx] + g * g
             delta = -lr * g / jnp.sqrt(acc_rows + self.eps) * mask
-            return (
-                param.at[idx].add(delta.astype(param.dtype)),
-                {"acc": state["acc"].at[idx].add(g * g)},
+            param, acc = add_rows(
+                (param, state["acc"]), idx, (delta.astype(param.dtype), g * g), live
             )
+            return param, {"acc": acc}
 
         if self.name == "adadelta":
             eg2_rows = self.rho * state["eg2"][idx] + (1 - self.rho) * g * g

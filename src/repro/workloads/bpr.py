@@ -34,7 +34,7 @@ import numpy as np
 from repro.core import mf
 from repro.core.ranks import effective_ranks, rank_mask
 from repro.data.ratings import RatingsDataset
-from repro.optim.optimizers import RowOptimizer
+from repro.optim.optimizers import RowOptimizer, rows_written
 from repro.workloads.implicit import _positive_sets, _sample_negatives
 
 
@@ -104,10 +104,8 @@ def _bpr_train_step(
     new_p, st_p = opt.apply_rows(params.p, opt_state.p, u, g_p, w_col, lr)
     idx_q = jnp.concatenate([i, j])
     g_q = jnp.concatenate([g_qi, g_qj])
-    new_q, st_q = opt.apply_rows(
-        params.q, opt_state.q, idx_q, g_q,
-        jnp.concatenate([w_col, w_col]), lr,
-    )
+    mask_q = jnp.concatenate([w_col, w_col])
+    new_q, st_q = opt.apply_rows(params.q, opt_state.q, idx_q, g_q, mask_q, lr)
     new_params = params._replace(p=new_p, q=new_q)
     new_state = opt_state._replace(p=st_p, q=st_q)
 
@@ -131,6 +129,13 @@ def _bpr_train_step(
         "work_fraction": jnp.sum(
             (rank_ui + rank_uj).astype(jnp.float32) * w
         ) / (denom * 2 * k),
+        # the sorts of the two apply_rows calls above, shared
+        "user_rows_share": rows_written(
+            u, jnp.any(w_col != 0, axis=-1), params.p.shape[0]
+        ) / u.shape[0],
+        "item_rows_share": rows_written(
+            idx_q, jnp.any(mask_q != 0, axis=-1), params.q.shape[0]
+        ) / idx_q.shape[0],
     }
     return new_params, new_state, metrics
 

@@ -27,6 +27,7 @@ from repro.core import mf
 from repro.kernels.fused_mf_sgd import fused_mf_sgd_padded
 from repro.kernels.pruned_matmul import pruned_matmul_padded
 from repro.kernels.pruned_topk import pruned_topk_padded
+from repro.kernels.row_write import write_rows
 from repro.launch.mesh import make_mesh
 from repro.optim.optimizers import RowOptimizer
 
@@ -114,6 +115,22 @@ def test_fused_mf_sgd_compiles(one_chip):
     compiled = fused_mf_sgd_padded.lower(
         rows, rows, col, col, col, col, scalar, scalar, scalar,
         lr=0.05, lam=0.02, interpret=False,
+    ).compile()
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize(
+    "rows", [162_541, 62_423], ids=["ml25m_users", "ml25m_items"]
+)
+def test_write_rows_compiles(one_chip, rows):
+    """The training step's row write at the ml25m_k128 tables: a factor
+    table and its Adagrad table, in place, for a 4,096-rating batch."""
+    b = 4096
+    compiled = write_rows.lower(
+        _sds((b,), jnp.int32, one_chip),
+        (_sds((b, K), jnp.float32, one_chip),) * 2,
+        (_sds((rows, K), jnp.float32, one_chip),) * 2,
+        interpret=False,
     ).compile()
     assert _has_kernel(compiled)
 
