@@ -305,7 +305,9 @@ def _train_step(
 
     # Gradients of 0.5*err^2 + 0.5*lam*||.||^2 wrt the gathered rows; the
     # paper's update p += lr*(err*q - lam*p) is descent on exactly this.
-    g_p = (lam * pu - err[:, None] * qi).astype(jnp.float32)
+    # SVD++ (Koren 2008, section 4) penalises p_u itself, not the user
+    # vector pu = p_u + |N(u)|^-1/2 sum y_j that the error and g_q use.
+    g_p = (lam * params.p[u] - err[:, None] * qi).astype(jnp.float32)
     g_q = (lam * qi - err[:, None] * pu).astype(jnp.float32)
 
     new_p, st_p = opt.apply_rows(params.p, opt_state.p, u, g_p, mask, lr)
@@ -327,41 +329,42 @@ def _train_step(
         new_state = new_state._replace(user_bias=st_bu, item_bias=st_bi)
 
     if params.implicit is not None and hist is not None:
-        # dL/dy_j = -err * q_i / sqrt(|N(u)|) for each j in N(u), masked.
+        # dL/dy_j = lam*y_j - err * q_i / sqrt(|N(u)|) for each j in N(u),
+        # masked by the rating's mask; padding slots add nothing
         n_items = params.implicit.shape[0] - 1
         counts = jnp.sum((hist < n_items).astype(jnp.float32), axis=1, keepdims=True)
         coef = err[:, None] * jax.lax.rsqrt(jnp.maximum(counts, 1.0))
-        # pred_mask here, not mask: the row weight rides in via flat_mask
-        # below (apply_rows multiplies it in) — using mask would square it
-        g_y = -(coef[:, None, :] * (qi * pred_mask)[:, None, :]) * jnp.ones(
-            (1, hist.shape[1], 1), jnp.float32
-        )
-        g_y = g_y + lam * params.implicit[hist]
+        g_y = lam * params.implicit[hist] - (coef * qi)[:, None, :]
         flat_idx = hist.reshape(-1)
-        flat_g = g_y.reshape(-1, k)
         flat_mask = jnp.repeat(mask, hist.shape[1], axis=0) * (
             flat_idx < n_items
         ).astype(jnp.float32)[:, None]
         new_y, st_y = opt.apply_rows(
-            params.implicit, opt_state.implicit, flat_idx, flat_g, flat_mask, lr
+            params.implicit, opt_state.implicit, flat_idx,
+            g_y.reshape(-1, k).astype(jnp.float32), flat_mask, lr,
         )
-        new_y = new_y.at[n_items].set(0.0)  # keep the padding row inert
         new_params = new_params._replace(implicit=new_y)
         new_state = new_state._replace(implicit=st_y)
+        flat_live = jnp.any(flat_mask != 0, axis=-1)
+        rows = rows + (
+            rows_written(flat_idx, flat_live, n_items + 1)
+            / jnp.maximum(jnp.sum(flat_live), 1),
+        )
 
     return new_params, new_state, _step_metrics(err, w, pair_ranks, k, rows)
 
 
 def _step_metrics(err, w, pair_ranks, k, rows) -> Dict[str, jax.Array]:
     """A step's weighted means, and the share of the batch's ids that are
-    rows written, user side and item side."""
+    rows written, user side and item side (and SVD++'s rows of the
+    implicit table written over its live history occurrences)."""
     denom = jnp.maximum(jnp.sum(w), 1e-9)  # weighted mean, not deflated
+    names = ("user_rows_share", "item_rows_share", "implicit_rows_share")
     return {
         "abs_err": jnp.sum(jnp.abs(err) * w) / denom,
         "work_fraction": jnp.sum(pair_ranks.astype(jnp.float32) * w)
         / (denom * k),
-        "user_rows_share": rows[0],
-        "item_rows_share": rows[1],
+        **dict(zip(names, rows)),
     }
 
 

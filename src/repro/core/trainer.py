@@ -107,9 +107,11 @@ class EpochRecord:
     straggler_slabs: int = 0       # slabs flagged as wall-time outliers
     step_retries: int = 0          # slab retries consumed this epoch
     # mean share of a step's ids that are distinct rows of P / Q written
-    # (NaN on the store path, which logs only the error and the work)
+    # (NaN on the store path, which logs only the error and the work), and
+    # SVD++'s distinct rows of Y written over its live history occurrences
     user_rows_share: float = float("nan")
     item_rows_share: float = float("nan")
+    implicit_rows_share: float = float("nan")
 
 
 class DPMFTrainer:
@@ -199,11 +201,15 @@ class DPMFTrainer:
                 )
             elif train_ds is None:
                 raise ValueError("either train_ds or config.store_dir is required")
-            self.hist = (
-                build_user_history(train_ds, config.max_hist)
-                if config.variant == "svdpp"
-                else None
-            )
+            self.hist = None
+            if config.variant == "svdpp":
+                with tracing.span("repro.trainer.history") as sp:
+                    self.hist, truncated = build_user_history(train_ds, config.max_hist)
+                    sp.set_metadata(
+                        users=train_ds.num_users,
+                        slots=int(np.sum(self.hist < train_ds.num_items)),
+                        truncated=truncated,
+                    )
             if config.epoch_mode == "scan":
                 # Upload the ratings (and eval set / SVD++ history) ONCE;
                 # per-epoch reshuffles happen on device (data/loader.py).  The
@@ -558,7 +564,7 @@ class DPMFTrainer:
             work = float(epoch_metrics["work_fraction"])
             rows_shares = {
                 key: float(epoch_metrics[key])
-                for key in ("user_rows_share", "item_rows_share")
+                for key in ("user_rows_share", "item_rows_share", "implicit_rows_share")
                 if key in epoch_metrics
             }
             wall = time.perf_counter() - start

@@ -160,17 +160,23 @@ def load_csv(
 
 def build_user_history(
     ds: RatingsDataset, max_hist: int = 32
-) -> np.ndarray:
-    """(num_users, max_hist) padded item ids for SVD++'s implicit term.
+) -> Tuple[np.ndarray, int]:
+    """SVD++'s N(u) for every user, and how many users it cuts.
 
-    Padding value is ``num_items`` — the inert extra row of the implicit
-    factor table.
+    Returns ``(hist, truncated)``: ``hist`` is (num_users, max_hist), each
+    row the distinct items the user rated (Koren 2008's set N(u)) in
+    ascending id order, padded with ``num_items`` -- the inert extra row of
+    the implicit factor table; ``truncated`` counts the users with more
+    than ``max_hist`` distinct items, each of whom keeps the first
+    ``max_hist``.
     """
+    pairs = np.unique(
+        np.asarray(ds.user, np.int64) * ds.num_items + np.asarray(ds.item, np.int64)
+    )
+    user, item = np.divmod(pairs, ds.num_items)
+    slot = np.arange(pairs.size) - np.searchsorted(user, user)
+    keep = slot < max_hist
     hist = np.full((ds.num_users, max_hist), ds.num_items, np.int32)
-    counts = np.zeros(ds.num_users, np.int32)
-    for u, i in zip(ds.user, ds.item):
-        c = counts[u]
-        if c < max_hist:
-            hist[u, c] = i
-            counts[u] = c + 1
-    return hist
+    hist[user[keep], slot[keep]] = item[keep]
+    sizes = np.bincount(user, minlength=ds.num_users)
+    return hist, int(np.sum(sizes > max_hist))

@@ -11,6 +11,10 @@ and starts one row DMA from the values into every table for each id inside
 the table, with at most ``IN_FLIGHT`` rows outstanding.  XLA's
 own scatter on the TPU reads, adds and writes one update row at a time,
 duplicates or not; this kernel only streams DMAs.
+
+A row DMA on the TPU moves whole 128-lane tiles, so the kernel takes the
+tables whose width is a multiple of ``LANES``.  A narrower table (a bias
+column) is written by XLA's scatter of the same distinct rows.
 """
 from __future__ import annotations
 
@@ -21,6 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128      # a row DMA's width must be a multiple of the lane tile
 IN_FLIGHT = 16   # rows outstanding before the kernel waits for one
 UNROLL = 8       # ids handled per loop iteration
 
@@ -86,6 +91,21 @@ def write_rows(
 ) -> tuple:
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    wide = [t for t, table in enumerate(tables) if table.shape[-1] % LANES == 0]
+    out = [table if t in wide
+           else table.at[rows].set(values[t], mode="drop", unique_indices=True)
+           for t, table in enumerate(tables)]
+    if wide:
+        written = _dma_rows(
+            rows, tuple(values[t] for t in wide), tuple(tables[t] for t in wide),
+            interpret=interpret,
+        )
+        for t, table in zip(wide, written):
+            out[t] = table
+    return tuple(out)
+
+
+def _dma_rows(rows, values: tuple, tables: tuple, *, interpret: bool) -> tuple:
     num_tables = len(tables)
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(

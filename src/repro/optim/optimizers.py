@@ -33,6 +33,9 @@ import jax
 import jax.numpy as jnp
 
 Pytree = Any
+# longest batch of occurrences whose run sums double (on a v5e, two
+# (4096, 128) arrays: doubling 45 us, the segmented scan 68 us)
+DOUBLING_MAX = 1 << 14
 
 
 # ---------------------------------------------------------------------------
@@ -61,15 +64,27 @@ def _row_runs(idx: jax.Array, live: jax.Array, num_rows: int):
     return order, jnp.where(tail, key, num_rows + pos), head
 
 
+def _segment_add(a, b):
+    """The segmented sum's operator on (run starts here, partial sum)."""
+    (head_a, sum_a), (head_b, sum_b) = a, b
+    return head_a | head_b, jnp.where(head_b[:, None], sum_b, sum_a + sum_b)
+
+
 def _run_sums(x: jax.Array, head: jax.Array) -> jax.Array:
     """Inclusive sums of ``x`` (B, w) along each run that ``head`` starts.
 
+    A run's last position ends up holding the sum of the whole run; a run
+    of one keeps its value bit for bit.  Up to ``DOUBLING_MAX`` rows,
     Hillis-Steele doubling: at distance ``d`` a position adds the partial
-    sum ``d`` back when that position lies in its own run.  A run's last
-    position ends up holding the sum of the whole run; a run of one keeps
-    its value bit for bit.  The doubling stops at the longest run, which
-    on the user side of a batch is a few occurrences."""
+    sum ``d`` back when that position lies in its own run, stopping at the
+    longest run, which on the user side of a batch is a few occurrences.
+    Past it (SVD++'s history slots, 131,072 a step at batch 4,096) the
+    doubling's full passes, one per doubling of a ~3,700-long run, cost
+    more than a work-efficient scan, whose cost does not depend on the
+    runs."""
     size = x.shape[0]
+    if size > DOUBLING_MAX:
+        return jax.lax.associative_scan(_segment_add, (head, x))[1]
     pos = jnp.arange(size, dtype=jnp.int32)
     start = jax.lax.cummax(jnp.where(head, pos, 0))
     longest = jnp.max(pos - start) + 1

@@ -37,9 +37,29 @@ def test_loader_eval_padding_weights():
 
 def test_user_history_padding():
     ds = synthetic_ratings(20, 30, 500, seed=0)
-    hist = build_user_history(ds, max_hist=8)
+    hist, _ = build_user_history(ds, max_hist=8)
     assert hist.shape == (20, 8)
     assert hist.max() <= 30  # padding value == num_items
+
+
+def test_user_history_is_the_set_of_rated_items():
+    """Koren's N(u): each user's distinct items, in ascending id order,
+    cut at max_hist; against a plain loop, on data with repeated pairs."""
+    rng = np.random.default_rng(3)
+    ds = synthetic_ratings(40, 30, 900, seed=1)
+    again = rng.choice(len(ds), 300)   # repeat 300 (user, item) pairs
+    ds.user = np.concatenate([ds.user, ds.user[again]])
+    ds.item = np.concatenate([ds.item, ds.item[again]])
+    ds.rating = np.concatenate([ds.rating, ds.rating[again]])
+    hist, truncated = build_user_history(ds, max_hist=12)
+    want = np.full((40, 12), 30, np.int32)
+    cut = 0
+    for u in range(40):
+        items = sorted({int(i) for uu, i in zip(ds.user, ds.item) if uu == u})
+        cut += len(items) > 12
+        want[u, : min(len(items), 12)] = items[:12]
+    np.testing.assert_array_equal(hist, want)
+    assert truncated == cut > 0
 
 
 def test_neighbor_sampler_is_valid_subgraph():

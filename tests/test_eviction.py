@@ -91,7 +91,7 @@ def test_bind_rejects_svdpp(tmp_path):
     from repro.data import build_user_history, synthetic_ratings
 
     ds = synthetic_ratings(12, 20, 256, seed=0)
-    hist = build_user_history(ds, max_hist=4)
+    hist, _ = build_user_history(ds, max_hist=4)
     upd = OnlineUpdater(
         _params(12, 20, variant="svdpp"), None, 0.0, 0.0,
         user_history=hist, batch_size=8,

@@ -162,7 +162,7 @@ def test_train_step_zero_weight_rows_are_inert():
 def test_svdpp_apply_appends_history_and_touches_implicit():
     ds = synthetic_ratings(20, 25, 300, seed=0)
     params = _params(20, 25, 8, variant="svdpp")
-    hist = build_user_history(ds, 4)
+    hist, _ = build_user_history(ds, 4)
     upd = OnlineUpdater(params, None, 0.0, 0.0, user_history=hist,
                         batch_size=8)
     # user 3 rates a brand-new-to-them item

@@ -15,8 +15,8 @@ import numpy as np
 import pytest
 
 from repro.core import mf
-from repro.kernels.row_write import write_rows
-from repro.optim.optimizers import RowOptimizer, rows_written
+from repro.kernels.row_write import LANES, write_rows
+from repro.optim.optimizers import DOUBLING_MAX, RowOptimizer, rows_written
 
 ROWS, K, B = 300, 16, 256
 LR, EPS = 0.05, 1e-8
@@ -83,6 +83,29 @@ def test_combined_write_matches_sequential_adds(name, pattern):
     np.testing.assert_array_equal(got_p[untouched], param[untouched])
 
 
+@pytest.mark.parametrize("name", ["sgd", "adagrad"])
+def test_long_batch_write_matches_sequential_adds(name):
+    """Past ``DOUBLING_MAX`` occurrences (SVD++'s history slots) the run
+    sums take the segmented scan: the same sums in another float32 order,
+    a quarter of the batch on one row."""
+    rng = np.random.default_rng(5)
+    size = DOUBLING_MAX + 1000
+    param = rng.normal(0, 0.1, (ROWS, K)).astype(np.float32)
+    acc = rng.uniform(0, 0.5, (ROWS, K)).astype(np.float32)
+    idx = np.minimum(rng.zipf(1.3, size) - 1, ROWS - 1).astype(np.int32)
+    idx[rng.permutation(size)[: size // 4]] = 3
+    grad = rng.normal(0, 1.0, (size, K)).astype(np.float32)
+    mask = (rng.uniform(size=(size, K)) < 0.7).astype(np.float32)
+    got_p, got_acc = _apply(name, param, acc, idx, grad, mask)
+    want_p, want_acc = _reference(name, param, acc, idx, grad, mask)
+    # float32 sums of up to ~4,500 terms of order 0.05 (deltas) or 1 (g^2)
+    np.testing.assert_allclose(got_p, want_p, rtol=1e-4, atol=1e-4)
+    if name == "adagrad":
+        np.testing.assert_allclose(got_acc, want_acc, rtol=1e-4, atol=1e-3)
+    untouched = np.setdiff1d(np.arange(ROWS), idx)
+    np.testing.assert_array_equal(got_p[untouched], param[untouched])
+
+
 @pytest.mark.parametrize("pattern", ["distinct", "one_row", "zipf"])
 @pytest.mark.parametrize("name", ["sgd", "adagrad"])
 def test_weight_zero_rows_are_bitwise_inert(name, pattern):
@@ -121,24 +144,41 @@ def test_rows_written_counts_distinct_live_ids(pattern):
     assert got == np.unique(idx[live]).size
 
 
-@pytest.mark.parametrize("num_tables,size", [(1, B), (2, B), (2, B - 3)])
-def test_write_rows_kernel_writes_only_listed_rows(num_tables, size):
+def _write_only_listed_rows(widths, size, interpret):
     rng = np.random.default_rng(3)
     rows = np.full(size, ROWS + 9, np.int32)            # past the table: skipped
     chosen = rng.choice(ROWS, 100, replace=False)
     rows[rng.choice(size, 100, replace=False)] = chosen
-    values = [rng.normal(size=(size, K)).astype(np.float32) for _ in range(num_tables)]
-    tables = [rng.normal(size=(ROWS, K)).astype(np.float32) for _ in range(num_tables)]
+    values = [rng.normal(size=(size, w)).astype(np.float32) for w in widths]
+    tables = [rng.normal(size=(ROWS, w)).astype(np.float32) for w in widths]
     want = [t.copy() for t in tables]
     for j in np.flatnonzero(rows < ROWS):
         for w, v in zip(want, values):
             w[rows[j]] = v[j]
     got = write_rows(
         jnp.asarray(rows), tuple(map(jnp.asarray, values)),
-        tuple(map(jnp.asarray, tables)), interpret=True,
+        tuple(map(jnp.asarray, tables)), interpret=interpret,
     )
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), w)
+
+
+@pytest.mark.parametrize("num_tables,size", [(1, B), (2, B), (2, B - 3)])
+def test_write_rows_kernel_writes_only_listed_rows(num_tables, size):
+    """The row-DMA kernel (interpreted here), which takes tables whose
+    rows are whole 128-lane tiles."""
+    _write_only_listed_rows([LANES] * num_tables, size, interpret=True)
+
+
+@pytest.mark.parametrize("widths", [(1,), (1, 1), (LANES, 1), (16,)],
+                         ids=["bias", "bias_and_acc", "factor_and_bias", "k16"])
+def test_write_rows_narrow_tables_write_only_listed_rows(widths):
+    """A table narrower than a lane tile (a bias column) is written by
+    XLA's scatter of the same rows, beside the kernel's wide tables: with
+    ``interpret=False`` on the CPU only the narrow tables can be written
+    without the kernel."""
+    narrow_only = all(w % LANES for w in widths)
+    _write_only_listed_rows(widths, B - 3, interpret=not narrow_only)
 
 
 def test_train_step_reports_shares_of_rows_written():

@@ -161,6 +161,40 @@ def test_train_epoch_scan_fused_compiles(one_chip):
     assert _has_kernel(compiled)
 
 
+def test_train_epoch_scan_svdpp_compiles(one_chip, monkeypatch):
+    """The SVD++ epoch program of ``bookx_svdpp_k128.svdpp_epochs``: k 128,
+    histories of 32 slots, batches of 4,096, a 340,555-row implicit table
+    whose row write takes 131,072 ids a step (512 KiB of ids in SMEM,
+    half of it).  ``write_rows`` picks the kernel by backend, which is the
+    CPU here: the test steers it to the chip's branch."""
+    m, n, hist_len, steps, batch = 105_284, 340_554, 32, 8, 4096
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    opt = RowOptimizer(name="adagrad")
+    params = jax.eval_shape(
+        functools.partial(mf.init_params, num_users=m, num_items=n, k=K,
+                          variant="svdpp"),
+        jax.random.PRNGKey(0),
+    )
+    state = jax.eval_shape(functools.partial(mf.init_opt_state, opt=opt), params)
+    place = lambda x: one_chip  # noqa: E731
+    batches = {
+        "user": _sds((steps, batch), jnp.int32, one_chip),
+        "item": _sds((steps, batch), jnp.int32, one_chip),
+        "rating": _sds((steps, batch), jnp.float32, one_chip),
+    }
+    scalar = _sds((), jnp.float32, one_chip)
+    compiled = mf.train_epoch_scan.lower(
+        _abstract(params, place), _abstract(state, place), batches,
+        scalar, scalar, scalar, _sds((K,), jnp.float32, one_chip),
+        _sds((m, hist_len), jnp.int32, one_chip),
+        opt=opt, lam=0.02, interpret=False,
+    ).compile()
+    # the row-DMA kernel writes P, Q and Y; the bias columns go by scatter
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 3
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
 @pytest.mark.parametrize(
     "shape,names", [((4,), ("model",)), ((2, 2), ("data", "model"))],
     ids=["model4", "data2xmodel2"],

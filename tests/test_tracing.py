@@ -139,3 +139,46 @@ def test_trained_tables_unchanged_by_the_session(tmp_path):
         assert np.asarray(getattr(off.params, leaf)).tobytes() == \
             np.asarray(getattr(on.params, leaf)).tobytes()
     assert [r.train_abs_err for r in off.history] == [r.train_abs_err for r in on.history]
+
+
+def _svdpp_trainer():
+    ds = synthetic_ratings(30, 40, 600, seed=2)
+    train, test = train_test_split(ds, 0.2, seed=2)
+    return DPMFTrainer(
+        TrainConfig(k=8, epochs=2, batch_size=64, pruning_rate=0.3, seed=5,
+                    variant="svdpp", max_hist=8),
+        train, test)
+
+
+def test_history_span_counts_users_slots_and_cut(tmp_path):
+    """``repro.trainer.history`` wraps the SVD++ history build inside
+    ``repro.trainer.init``, with the users, the live slots (distinct
+    training pairs kept) and the users cut at ``max_hist``."""
+    trainer, spans = _traced(tmp_path, _svdpp_trainer)
+    (hist,) = [sp for sp in spans if sp[2] == "repro.trainer.history"]
+    assert _parent(spans, hist) == "repro.trainer.init"
+    ds = trainer.train_ds
+    sets = [set(ds.item[ds.user == u].tolist()) for u in range(ds.num_users)]
+    assert hist[3] == {
+        "users": 30,
+        "slots": sum(min(len(s), 8) for s in sets),
+        "truncated": sum(len(s) > 8 for s in sets),
+    }
+    assert hist[3]["truncated"] > 0   # 16 training ratings a user: some cut
+
+
+def test_implicit_rows_share_counts_distinct_rows_of_y():
+    """The epoch record's ``implicit_rows_share`` is the mean over steps of
+    the distinct rows of Y written over the live history occurrences; the
+    funk trainer has none."""
+    trainer = _svdpp_trainer()
+    batches = trainer._packed_train.epoch_batches(trainer.config.seed, 0)
+    record = trainer.run_epoch()   # dense: every occurrence of a set is live
+    hist = trainer.hist
+    shares = []
+    for users in np.asarray(batches["user"]):
+        slots = hist[users].reshape(-1)
+        live = slots[slots < trainer.train_ds.num_items]
+        shares.append(np.unique(live).size / live.size)
+    assert record.implicit_rows_share == pytest.approx(np.mean(shares), rel=1e-6)
+    assert np.isnan(_trainer().run_epoch().implicit_rows_share)

@@ -99,7 +99,7 @@ def test_scan_epoch_matches_per_batch_loop(packed, opt_name):
 def test_scan_epoch_variants(packed, variant):
     ds = synthetic_ratings(M, N, 6000, seed=1)
     hist = (
-        jnp.asarray(build_user_history(ds, 8)) if variant == "svdpp" else None
+        jnp.asarray(build_user_history(ds, 8)[0]) if variant == "svdpp" else None
     )
     opt = RowOptimizer(name="adagrad")
     batches = packed.epoch_batches(0, 3)
