@@ -220,7 +220,7 @@ def pruned_topk(
     qp, rip, biasp = pad_catalog_for_topk_kernel(
         q, r_i, item_bias, block_n=block_n, block_k=block_k
     )
-    scores, idx = pruned_topk_padded(
+    scores, idx, _ = pruned_topk_padded(
         pp, qp, rup, rip, biasp,
         topk=topk,
         n_items=n,

@@ -18,10 +18,19 @@ Structure (reuses the ragged-K tile skipping of ``pruned_matmul.py``):
 * partially-covered K-blocks are element-masked with ``broadcasted_iota`` so
   scores are exactly the oracle's;
 * on the last K-block the (bm, bn) score tile is merged into the running
-  (bm, topk) scores/indices scratch by iterative max-extraction (k vector
-  passes — no sort network needed on the VPU; ties resolve to the lower item
-  index, matching a stable dense argsort);
-* the merged result is written to the output only on the final item tile.
+  (bm, topk) scores/indices scratch, sorted descending.  One compare and
+  row-sum counts, per row, the tile scores strictly above the row's running
+  k-th best; the merge then runs as many passes as the block's largest such
+  count (capped at ``topk``), none when it is 0.  Each pass extracts every
+  row's best remaining tile score (first match, so the lower item index
+  first) and inserts it behind the running entries ``>=`` it, shifting the
+  tail one lane, if it beats the row's k-th best.  Tiles arrive in item
+  order, so a tile score equal to the k-th best never enters: the tie order
+  of a stable dense argsort.  On catalogs in random order the count falls
+  to a few passes a tile after the first tiles; a catalog whose scores rise
+  along its order takes ``topk`` passes every tile;
+* the merged result is written to the output only on the final item tile,
+  with the block's merge passes summed over its item tiles.
 
 Peak HBM for serving B users is therefore O(B * topk) instead of O(B * N).
 """
@@ -44,43 +53,37 @@ TOPK_BLOCK_N = 256
 TOPK_BLOCK_K = 128
 
 
-def _merge_topk(run_s, run_i, tile_s, tile_i, topk: int):
-    """Merge a (bm, bn) score tile into the (bm, P) running top-k buffers.
+def _merge_pass(tile_ref, ts_ref, ti_ref, first_item, topk: int):
+    """Move each row's best remaining score of the tile in ``tile_ref`` into
+    the running buffers if it strictly beats the row's k-th best.
 
-    Iterative max-extraction: ``topk`` passes of rowwise max + first-match
-    select over the concatenated candidates.  First-match (minimum position)
-    prefers the running buffer, i.e. earlier = lower item indices, which is
-    exactly the tie order of a stable dense argsort.
-    """
-    bm = run_s.shape[0]
-    cand_s = jnp.concatenate([run_s, tile_s], axis=1)
-    cand_i = jnp.concatenate([run_i, tile_i], axis=1)
-    width = cand_s.shape[1]
-    pos = jax.lax.broadcasted_iota(jnp.int32, (bm, width), 1)
+    The score goes behind the running entries ``>=`` it (equal running
+    entries have lower item indices and stay in front), the tail shifts one
+    lane and the last entry drops.  A score that does not beat the k-th
+    best has at least ``topk`` entries in front of it, so its row is left
+    as it is."""
+    bm, bn = tile_ref.shape
+    tile = tile_ref[...]
+    col = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
+    best = jnp.max(tile, axis=1, keepdims=True)
+    sel = jnp.min(jnp.where(tile == best, col, bn), axis=1, keepdims=True)
+    tile_ref[...] = jnp.where(col == sel, _NEG_INF, tile)
 
-    out_s, out_i = [], []
-    for _ in range(topk):
-        best = jnp.max(cand_s, axis=1, keepdims=True)
-        sel = jnp.min(
-            jnp.where((cand_s == best) & (best > _NEG_INF), pos, width),
-            axis=1,
-            keepdims=True,
-        )
-        hit = pos == sel  # one-hot row mask; all-False once a row runs dry
-        out_s.append(jnp.max(jnp.where(hit, cand_s, _NEG_INF), axis=1, keepdims=True))
-        out_i.append(jnp.max(jnp.where(hit, cand_i, 0), axis=1, keepdims=True))
-        cand_s = jnp.where(hit, _NEG_INF, cand_s)
-
-    pad = run_s.shape[1] - topk
-    if pad:
-        out_s.append(jnp.full((bm, pad), _NEG_INF, run_s.dtype))
-        out_i.append(jnp.zeros((bm, pad), run_i.dtype))
-    return jnp.concatenate(out_s, axis=1), jnp.concatenate(out_i, axis=1)
+    run_s, run_i = ts_ref[...], ti_ref[...]
+    pos = jax.lax.broadcasted_iota(jnp.int32, run_s.shape, 1)
+    at = jnp.sum((run_s >= best).astype(jnp.int32), axis=1, keepdims=True)
+    write = (pos >= at) & (pos < topk)
+    ts_ref[...] = jnp.where(
+        write, jnp.where(pos == at, best, pltpu.roll(run_s, 1, 1)), run_s
+    )
+    ti_ref[...] = jnp.where(
+        write, jnp.where(pos == at, first_item + sel, pltpu.roll(run_i, 1, 1)), run_i
+    )
 
 
 def _kernel(
-    p_ref, q_ref, ru_ref, ri_ref, bias_ref, os_ref, oi_ref,
-    acc_ref, ts_ref, ti_ref,
+    p_ref, q_ref, ru_ref, ri_ref, bias_ref, os_ref, oi_ref, op_ref,
+    acc_ref, ts_ref, ti_ref, np_ref,
     *, block_k: int, topk: int, n_items: int,
 ):
     jn, ik = pl.program_id(1), pl.program_id(2)
@@ -90,6 +93,7 @@ def _kernel(
     def _init_topk():
         ts_ref[...] = jnp.full_like(ts_ref, _NEG_INF)
         ti_ref[...] = jnp.zeros_like(ti_ref)
+        np_ref[0] = 0
 
     @pl.when(ik == 0)
     def _init_acc():
@@ -124,14 +128,30 @@ def _kernel(
         scores = acc_ref[...] + bias_ref[...].reshape(1, bn)
         # padded catalog rows (q rows past n_items) must never be selected
         scores = jnp.where(gidx < n_items, scores, _NEG_INF)
-        new_s, new_i = _merge_topk(ts_ref[...], ti_ref[...], scores, gidx, topk)
-        ts_ref[...] = new_s
-        ti_ref[...] = new_i
+        # each row's running k-th best: the least of the first topk columns
+        # of its descending buffer
+        pos = jax.lax.broadcasted_iota(jnp.int32, ts_ref.shape, 1)
+        kth = jnp.min(
+            jnp.where(pos < topk, ts_ref[...], float("inf")), axis=1, keepdims=True
+        )
+        above = jnp.sum((scores > kth).astype(jnp.int32), axis=1, keepdims=True)
+        passes = jnp.minimum(jnp.max(above), topk)
+        np_ref[0] += passes
+
+        def one_pass(_, carry):
+            _merge_pass(acc_ref, ts_ref, ti_ref, jn * bn, topk)
+            return carry
+
+        @pl.when(passes > 0)
+        def _insert():
+            acc_ref[...] = scores
+            jax.lax.fori_loop(0, passes, one_pass, 0)
 
     @pl.when((jn == nj - 1) & (ik == nk - 1))
     def _store():
         os_ref[...] = ts_ref[...]
         oi_ref[...] = ti_ref[...]
+        op_ref[...] = jnp.full(op_ref.shape, np_ref[0], jnp.int32)
 
 
 @functools.partial(
@@ -154,9 +174,11 @@ def pruned_topk_padded(
     block_k: int = TOPK_BLOCK_K,
     interpret: bool = False,
 ):
-    """Padded-shape kernel entry.  Returns ``(scores, indices)`` shaped
-    (M, topk_pad) with ``topk_pad = topk`` rounded up to the 128-lane tile;
-    columns past ``topk`` are -inf / 0 filler."""
+    """Padded-shape kernel entry.  Returns ``(scores, indices, passes)``:
+    scores and indices shaped (M, topk_pad) with ``topk_pad = topk`` rounded
+    up to the 128-lane tile, columns past ``topk`` -inf / 0 filler; passes
+    (M // block_m, 1, 128) int32, every lane of row ``b`` the merge passes
+    user block ``b`` ran, summed over its item tiles."""
     m, k = p.shape
     n = q.shape[0]
     topk_pad = -(-topk // 128) * 128
@@ -178,15 +200,18 @@ def pruned_topk_padded(
         out_specs=[
             pl.BlockSpec((block_m, topk_pad), lambda im, jn, ik: (im, 0)),
             pl.BlockSpec((block_m, topk_pad), lambda im, jn, ik: (im, 0)),
+            pl.BlockSpec((None, 1, 128), lambda im, jn, ik: (im, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, topk_pad), jnp.float32),
             jax.ShapeDtypeStruct((m, topk_pad), jnp.int32),
+            jax.ShapeDtypeStruct((m // block_m, 1, 128), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_m, block_n), jnp.float32),
             pltpu.VMEM((block_m, topk_pad), jnp.float32),
             pltpu.VMEM((block_m, topk_pad), jnp.int32),
+            pltpu.SMEM((1,), jnp.int32),
         ],
         # the N (item-tile) axis carries the running top-k scratch, so only
         # the user-tile axis is parallel

@@ -405,6 +405,13 @@ class ServingEngine:
     The model state behind those entry points is a versioned snapshot;
     :meth:`swap` atomically publishes a new one (see the module docstring
     for the consistency contract).
+
+    ``merge_passes`` / ``merge_tiles`` count, over the engine's life, the
+    Pallas kernel's top-k merge passes and the (user block, item tile)
+    pairs they ran over, fetched with each launch's answers; the
+    streaming and sharded paths add nothing.  Like the rest of the
+    scoring state they are written by the one thread that scores (see
+    :meth:`start`).
     """
 
     def __init__(
@@ -458,6 +465,8 @@ class ServingEngine:
         self._queue_lock = threading.Lock()  # guards _queue transitions
         self._stopping = False               # stop() drain in progress
         self._swap_lock = threading.Lock()   # serializes swap() builders
+        self.merge_passes = 0
+        self.merge_tiles = 0
 
     @staticmethod
     def _resolve_history(params, user_history, allow_missing_history):
@@ -833,6 +842,8 @@ class ServingEngine:
         return pu.astype(jnp.float32) * rank_mask(r_u, snap.k)
 
     def _topk_block(self, snap: _Snapshot, pu: jnp.ndarray, topk: int):
+        """``(scores, indices, passes)`` of one launch; ``passes`` is the
+        kernel's merge-pass counter, None on the streaming path."""
         if self.use_kernel:
             return self._topk_block_kernel(snap, pu, topk)
         q_tiles, b_tiles, offs = snap.stream_layout()
@@ -841,18 +852,18 @@ class ServingEngine:
             # latent-compacted layout: user columns past the catalog's max
             # effective rank only ever multiply zeros — drop them too
             pm = pm[:, : q_tiles.shape[2]]
-        return stream_topk_tiles(pm, q_tiles, b_tiles, offs, topk=topk)
+        return (*stream_topk_tiles(pm, q_tiles, b_tiles, offs, topk=topk), None)
 
     def _topk_block_kernel(self, snap: _Snapshot, pu: jnp.ndarray, topk: int):
         qp, rip, biasp = snap.kernel_layout()
         r_u = effective_ranks(pu, snap.t_p)
         pp, rup = pad_users_for_topk_kernel(pu, r_u)
-        scores, idx = pruned_topk_padded(
+        scores, idx, passes = pruned_topk_padded(
             pp, qp, rup, rip, biasp,
             topk=topk, n_items=snap.n_items,
             interpret=self._interpret(),
         )
-        return scores[: pu.shape[0], :topk], idx[: pu.shape[0], :topk]
+        return scores[: pu.shape[0], :topk], idx[: pu.shape[0], :topk], passes
 
     def _interpret(self) -> bool:
         return (
@@ -917,7 +928,9 @@ class ServingEngine:
     def _run_chunked(self, snap: _Snapshot, ids: np.ndarray, topk: int, block_fn):
         """Shared request loop: split into max_batch chunks, pad each chunk
         to its power-of-two bucket (bounds the jit cache to log2(max_batch)
-        shapes per scoring program), score, fold user constants back in."""
+        shapes per scoring program), score, fold user constants back in.
+        ``block_fn`` returns ``(scores, indices, passes)``: the answers and
+        the kernel's merge-pass counter (or None), fetched in one transfer."""
         out_s = np.empty((len(ids), topk), np.float32)
         out_i = np.empty((len(ids), topk), np.int32)
         for lo in range(0, len(ids), self.max_batch):
@@ -929,10 +942,15 @@ class ServingEngine:
             with tracing.span(
                 "repro.serving.launch", users=len(chunk), bucket=bucket
             ):
-                scores, idx = block_fn(pu, topk)
+                launched = block_fn(pu, topk)
             with tracing.span("repro.serving.fetch"):
-                scores = np.asarray(scores[: len(chunk)])
-                idx = np.asarray(idx[: len(chunk)])
+                scores, idx, passes = jax.device_get(launched)
+                scores, idx = scores[: len(chunk)], idx[: len(chunk)]
+                if passes is not None:
+                    self.merge_passes += int(passes[:, 0, 0].sum())
+                    self.merge_tiles += passes.shape[0] * -(
+                        -snap.n_items // TOPK_BLOCK_N
+                    )
                 if snap.user_const is not None:
                     scores = scores + snap.user_const[chunk][:, None]
                 out_s[lo : lo + len(chunk)] = scores
@@ -979,7 +997,7 @@ class ServingEngine:
                     pp, rup = pad_users_for_topk_kernel(pu_blk, r_u)
                     # padding rows inside the slab carry -inf bias, so every
                     # slab can claim its full extent as valid items
-                    s, i = pruned_topk_padded(
+                    s, i, _ = pruned_topk_padded(
                         pp, qp, rup, rip, biasp,
                         topk=topk, n_items=n_loc, interpret=interpret,
                     )
@@ -1059,7 +1077,7 @@ class ServingEngine:
                 scores, idx = fn(pm, snap.t_p, *layout)
             else:
                 scores, idx = fn(pm, *layout)
-            return scores[:b], idx[:b]
+            return scores[:b], idx[:b], None
 
         out_s, out_i = self._run_chunked(snap, ids, topk, block_fn)
         return self._apply_fallback(snap, evicted, topk, out_s, out_i)

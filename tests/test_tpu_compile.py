@@ -84,9 +84,15 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("topk", [10, 100])
-def test_pruned_topk_compiles(one_chip, topk):
-    m, n = 1024, 65_536
+@pytest.mark.parametrize(
+    "m,n,topk",
+    [(1024, 65_536, 10), (1024, 65_536, 100), (256, 340_736, 100)],
+    ids=["10", "100", "bookx_launch"],
+)
+def test_pruned_topk_compiles(one_chip, m, n, topk):
+    """The scoring kernel, its merge loop's traced trip count included;
+    ``bookx_launch`` is one 256-user launch over the BookCrossings catalog
+    as the engine pads it (340,554 items to 256-item tiles)."""
     f32, i32 = jnp.float32, jnp.int32
     compiled = pruned_topk_padded.lower(
         _sds((m, K), f32, one_chip), _sds((n, K), f32, one_chip),
