@@ -12,8 +12,6 @@ dpmf config's full width (k=128) on the paper's BookCrossings shape
 * **train** -- ``DPMFTrainer`` with Adagrad at pruning rate 0.3 for three
   epochs: a dense epoch, the threshold solve and the latent
   rearrangement, then two pruned epochs through ``train_epoch_scan``;
-* **fused** -- one more epoch from the trained state with plain SGD, once
-  through the fused Pallas SGD kernel and once through XLA, compared;
 * **serve** -- checkpoint -> ``ServingEngine.from_checkpoint`` -> top-100
   for 1,024 users through the Pallas ``pruned_topk`` kernel, compared with
   the streaming path, the dense oracle and a float64 host rescoring, at
@@ -38,7 +36,6 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import dataclasses
 import json
 import os
 import shutil
@@ -56,7 +53,6 @@ GOLD_USERS = 128        # users whose exact float64 top-k is computed on host
 ASYNC_REQUESTS = 48
 EVENT_BATCH = 1024
 EVENT_BATCHES = 4
-SGD_LR = 0.005          # plain SGD from the Adagrad-trained state
 OWNER_STEPS, OWNER_BATCH = 32, 4096   # owner-compute epoch (four chips)
 # float32 dot-product forward error: |fl(p.q) - p.q| <= k * u * sum|p_t q_t|
 # with unit roundoff u = 2**-24 (Higham, Accuracy and Stability, 3.1)
@@ -174,11 +170,9 @@ def exact_topk(p_rows, r_u, q, r_i, topk):
 
 
 def run_one_chip(smoke, args):
-    import jax
     import jax.numpy as jnp
 
     from repro.configs.dpmf import CONFIG as DPMF
-    from repro.core import mf
     from repro.core.ranks import effective_ranks
     from repro.core.trainer import DPMFTrainer, TrainConfig
     from repro.data.ratings import paper_dataset, train_test_split
@@ -226,45 +220,6 @@ def run_one_chip(smoke, args):
                     f"work fractions {[round(r.work_fraction, 3) for r in hist]}")
         smoke.check("finite factors", bool(
             jnp.isfinite(trainer.params.p).all() & jnp.isfinite(trainer.params.q).all()))
-
-    with smoke.phase("fused"):
-        interpret = kops._default_interpret()
-        smoke.check("kernels resolve to compiled (interpret=False)", not interpret)
-        runs = {}
-        for fused in (True, False):
-            cfg = dataclasses.replace(config, optimizer="sgd", lr=SGD_LR,
-                                      use_fused_kernel=fused, checkpoint_dir=None)
-            t = DPMFTrainer(cfg, train_ds, test_ds)
-            t.params = jax.tree.map(jnp.copy, trainer.params)
-            t.t_p, t.t_q, t.perm, t.epoch = (
-                trainer.t_p, trainer.t_q, trainer.perm, trainer.epoch)
-            if fused:
-                text = mf.train_epoch_scan.lower(
-                    t.params, t.opt_state,
-                    t._packed_train.epoch_batches(cfg.seed, t.epoch),
-                    t.t_p, t.t_q, jnp.float32(cfg.lr), jnp.ones((cfg.k,)), None,
-                    opt=t.opt, lam=cfg.lam, use_fused_kernel=True,
-                ).as_text()
-                smoke.check("tpu_custom_call in the fused SGD epoch program",
-                            "tpu_custom_call" in text)
-            rec = t.run_epoch()
-            runs[fused] = (t, rec)
-            print(f"  {'fused' if fused else 'xla  '} SGD epoch: train |err| "
-                  f"{rec.train_abs_err:.6f}  test MAE {rec.test_mae:.6f}  "
-                  f"wall {rec.wall_time_s:.2f} s")
-        (tf, rf), (tx, rx) = runs[True], runs[False]
-        dp = float(jnp.max(jnp.abs(tf.params.p - tx.params.p)))
-        dq = float(jnp.max(jnp.abs(tf.params.q - tx.params.q)))
-        rel = abs(rf.train_abs_err - rx.train_abs_err) / rx.train_abs_err
-        # The kernel applies each update at lr=1 and the step rescales
-        # (p + d) - p by lr, and its row reductions run in another order:
-        # the two paths agree to float32 rounding per step, compounded over
-        # the epoch's steps -- not bitwise.
-        smoke.check("fused kernel epoch == XLA epoch",
-                    np.isfinite([dp, dq, rf.train_abs_err]).all()
-                    and dp < 1e-4 and dq < 1e-4 and rel < 1e-4,
-                    f"max|dp| {dp:.3e}  max|dq| {dq:.3e}  rel d|err| {rel:.3e}")
-        del runs, tf, tx
 
     users = np.sort(rng.choice(ds.num_users, N_USERS_SERVED, replace=False)).astype(np.int32)
     with smoke.phase("serve"):

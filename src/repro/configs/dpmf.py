@@ -132,7 +132,7 @@ def _train_cell_owner_compute(batch: int, compress: bool = False) -> base.CellSp
         return mf.train_step_shard_map(
             params, opt_state, batch_d, t_p, t_q,
             lr=cfg.lr, lam=cfg.lam, opt_name=cfg.optimizer,
-            compress_grads=compress,
+            grad_compression="int8" if compress else "none",
         )
 
     a_params = base.abstract_like(init, jax.random.PRNGKey(0))

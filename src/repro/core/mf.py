@@ -208,16 +208,11 @@ def _train_step(
     *,
     opt: RowOptimizer,
     lam: float,
-    use_fused_kernel: bool = False,
-    interpret: Optional[bool] = None,
 ) -> Tuple[MFParams, MFOptState, Dict[str, jax.Array]]:
     """One minibatched, dynamically-pruned MF update (Algs. 2 + 3).
 
-    ``use_fused_kernel`` routes every plain-SGD case without implicit
-    feedback — FunkSVD *and* BiasSVD, weighted or not — through the fused
-    Pallas kernel (biases and the weight column ride along in-kernel); every
-    other (variant, optimizer) combination uses the masked XLA formulation
-    with identical semantics.  Duplicate (u, i) rows in a batch accumulate
+    Every (variant, optimizer) combination runs the same masked XLA
+    formulation.  Duplicate (u, i) rows in a batch accumulate
     additively (summed per row on the device and written once, see
     ``optim.optimizers.add_rows``), the standard minibatch relaxation of the
     paper's sequential SGD.
@@ -254,42 +249,6 @@ def _train_step(
         rows_written(u, live, params.p.shape[0]) / u.shape[0],
         rows_written(i, live, params.q.shape[0]) / i.shape[0],
     )
-
-    fused_ok = (
-        use_fused_kernel
-        and opt.name == "sgd"
-        and params.implicit is None
-    )
-    if fused_ok:
-        has_bias = params.user_bias is not None
-        new_pu, new_qi, new_bu, new_bi, err = kops.fused_mf_sgd(
-            params.p[u],
-            qi,
-            r,
-            t_p,
-            t_q,
-            lr=1.0,  # lr folded below so it can stay a traced array
-            lam=lam,
-            bias_u=params.user_bias[u, 0] if has_bias else None,
-            bias_i=params.item_bias[i, 0] if has_bias else None,
-            global_mean=params.global_mean if has_bias else 0.0,
-            weight=weight,
-            interpret=interpret,
-        )
-        # kernel computed rows at lr=1; rescale the delta by the traced lr and
-        # the strategy mask, then add each side's rows in one write.
-        dp = (new_pu - params.p[u]) * lr * dim_mask[None, :]
-        dq = (new_qi - qi) * lr * dim_mask[None, :]
-        (new_p,) = add_rows((params.p,), u, (dp,), live)
-        (new_q,) = add_rows((params.q,), i, (dq,), live)
-        new_params = params._replace(p=new_p, q=new_q)
-        if has_bias:
-            dbu = (new_bu - params.user_bias[u, 0]) * lr
-            dbi = (new_bi - params.item_bias[i, 0]) * lr
-            (new_bu,) = add_rows((params.user_bias,), u, (dbu[:, None],), w != 0)
-            (new_bi,) = add_rows((params.item_bias,), i, (dbi[:, None],), w != 0)
-            new_params = new_params._replace(user_bias=new_bu, item_bias=new_bi)
-        return new_params, opt_state, _step_metrics(err, w, pair_ranks, k, rows)
 
     pred = jnp.sum(
         pu.astype(jnp.float32) * qi.astype(jnp.float32) * pred_mask, axis=-1
@@ -368,10 +327,7 @@ def _step_metrics(err, w, pair_ranks, k, rows) -> Dict[str, jax.Array]:
     }
 
 
-train_step = jax.jit(
-    _train_step,
-    static_argnames=("opt", "lam", "use_fused_kernel", "interpret"),
-)
+train_step = jax.jit(_train_step, static_argnames=("opt", "lam"))
 
 
 def _eval_mae(
@@ -401,7 +357,7 @@ def _epoch_scan(step_fn, params, opt_state, batches):
     """``lax.scan`` of ``step_fn`` over packed ``(steps, B)`` batch arrays.
 
     Metrics accumulate on device (sum of per-batch means, divided once at the
-    end — identical to what the per-batch Python loop computes) so an epoch
+    end — identical to a per-batch loop of ``step_fn``) so an epoch
     costs exactly one host sync, taken by the *caller* when it fetches the
     returned scalars.
     """
@@ -430,7 +386,7 @@ def _epoch_scan(step_fn, params, opt_state, batches):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("opt", "lam", "use_fused_kernel", "interpret"),
+    static_argnames=("opt", "lam"),
     donate_argnums=(0, 1),
 )
 def train_epoch_scan(
@@ -445,28 +401,24 @@ def train_epoch_scan(
     *,
     opt: RowOptimizer,
     lam: float,
-    use_fused_kernel: bool = False,
-    interpret: Optional[bool] = None,
 ) -> Tuple[MFParams, MFOptState, Dict[str, jax.Array]]:
     """A whole epoch as ONE compiled, donated computation.
 
     Semantically a fold of :func:`train_step` over the packed batches —
-    ``train_step`` stays the single-step owner (the online updater and the
-    legacy trainer path call it directly); this is the same body traced once
-    into a ``lax.scan``, so the per-step dispatch/upload/sync overhead of
-    the Python loop disappears.  ``donate_argnums=(0, 1)`` lets XLA update
-    params and optimizer state in place across the epoch.  The SVD++
-    history table is passed whole and gathered per step on device, instead
-    of being packed into (steps, B, H) batch arrays.
+    ``train_step`` stays the single-step owner (the online updater calls it
+    directly); this is the same body traced once into a ``lax.scan``, so an
+    epoch pays no per-step dispatch, upload or sync.
+    ``donate_argnums=(0, 1)`` lets XLA update params and optimizer state in
+    place across the epoch.  The SVD++ history table is passed whole and
+    gathered per step on device, instead of being packed into (steps, B, H)
+    batch arrays.
     """
 
     def step(p, s, batch):
         if hist is not None:
             batch = dict(batch, hist=hist[batch["user"]])
         return _train_step(
-            p, s, batch, t_p, t_q, lr, dim_mask,
-            opt=opt, lam=lam,
-            use_fused_kernel=use_fused_kernel, interpret=interpret,
+            p, s, batch, t_p, t_q, lr, dim_mask, opt=opt, lam=lam
         )
 
     return _epoch_scan(step, params, opt_state, batches)
@@ -571,18 +523,6 @@ def _check_owner_compute_opt(opt_name: str) -> None:
         )
 
 
-def _resolve_grad_compression(grad_compression: str, compress_grads: bool) -> str:
-    """Normalize the two compression knobs: the legacy ``compress_grads``
-    bool maps to plain ``"int8"``; the string knob wins when both are set."""
-    if grad_compression == "none" and compress_grads:
-        return "int8"
-    if grad_compression not in ("none", "int8", "int8_ef"):
-        raise ValueError(
-            f"grad_compression must be none|int8|int8_ef, got {grad_compression!r}"
-        )
-    return grad_compression
-
-
 def init_error_feedback_state(
     params: MFParams, opt_state: MFOptState, mesh=None
 ) -> MFOptState:
@@ -640,7 +580,6 @@ def train_step_shard_map(
     lam: float,
     opt_name: str = "adagrad",
     eps: float = 1e-8,
-    compress_grads: bool = False,
     grad_compression: str = "none",
     mesh=None,
 ) -> Tuple[MFParams, MFOptState, Dict[str, jax.Array]]:
@@ -661,10 +600,10 @@ def train_step_shard_map(
         (B_loc, k) masked p-deltas cross the links; the q update never
         leaves its owner.
 
-    ``grad_compression="int8"`` (or the legacy ``compress_grads=True``)
-    int8-quantizes the p-gradient psum and the q-delta all-gather payloads
-    (4x fewer bytes on the dominant collectives; per-tensor scales psum'd
-    alongside).  Quantization error is bounded by scale/2 per element.
+    ``grad_compression="int8"`` int8-quantizes the p-gradient psum and the
+    q-delta all-gather payloads (4x fewer bytes on the dominant collectives;
+    per-tensor scales psum'd alongside).  Quantization error is bounded by
+    scale/2 per element.
     ``"int8_ef"`` adds per-sender error feedback: each rank keeps the
     residual its quantizer dropped (``init_error_feedback_state`` tables in
     ``opt_state``) and folds it into the next transmission of the same row
@@ -699,7 +638,9 @@ def train_step_shard_map(
     k = params.p.shape[1]
     _check_owner_compute_opt(opt_name)
     adagrad = opt_name == "adagrad"
-    gc = _resolve_grad_compression(grad_compression, compress_grads)
+    gc = grad_compression
+    if gc not in ("none", "int8", "int8_ef"):
+        raise ValueError(f"grad_compression must be none|int8|int8_ef, got {gc!r}")
 
     def body(p_blk, q_blk, acc_p, acc_q, ef_p, ef_q, u, i, r, w, t_p, t_q):
         # block-local coordinates
@@ -936,20 +877,18 @@ def train_step_shard_map(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "lr", "lam", "opt_name", "eps", "compress_grads", "grad_compression",
-        "mesh",
+        "lr", "lam", "opt_name", "eps", "grad_compression", "mesh",
     ),
     donate_argnums=(0, 1),
 )
 def _train_epoch_scan_shard_map(
     params, opt_state, batches, t_p, t_q,
-    *, lr, lam, opt_name, eps, compress_grads, grad_compression, mesh,
+    *, lr, lam, opt_name, eps, grad_compression, mesh,
 ):
     def step(p, s, batch):
         return train_step_shard_map(
             p, s, batch, t_p, t_q, lr=lr, lam=lam, opt_name=opt_name,
-            eps=eps, compress_grads=compress_grads,
-            grad_compression=grad_compression, mesh=mesh,
+            eps=eps, grad_compression=grad_compression, mesh=mesh,
         )
 
     return _epoch_scan(step, params, opt_state, batches)
@@ -966,7 +905,6 @@ def train_epoch_scan_shard_map(
     lam: float,
     opt_name: str = "adagrad",
     eps: float = 1e-8,
-    compress_grads: bool = False,
     grad_compression: str = "none",
     mesh=None,
 ) -> Tuple[MFParams, MFOptState, Dict[str, jax.Array]]:
@@ -988,6 +926,5 @@ def train_epoch_scan_shard_map(
         params, opt_state, batches,
         jnp.asarray(t_p, jnp.float32), jnp.asarray(t_q, jnp.float32),
         lr=float(lr), lam=float(lam), opt_name=opt_name, eps=float(eps),
-        compress_grads=bool(compress_grads),
         grad_compression=str(grad_compression), mesh=mesh,
     )

@@ -54,13 +54,10 @@ class TrainConfig:
     #           positives + sampled negatives with a confidence weight
     #           column riding train_step's batch["weight"] gate
     # bpr:      pairwise -log σ(s_ui - s_uj) on per-epoch sampled triples
-    #           (scan mode only; test_mae is NaN, ranking metrics carry)
+    #           (test_mae is NaN, ranking metrics carry)
     objective: str = "explicit"        # explicit | implicit | bpr
     implicit_alpha: float = 40.0       # confidence c = 1 + alpha·r
     implicit_negatives: int = 4        # sampled unobserved items / positive
-    use_fused_kernel: bool = False     # Pallas path (interpret mode on CPU)
-    epoch_mode: str = "scan"           # scan: one donated lax.scan per epoch
-    #                                  # python: legacy per-batch host loop
     seed: int = 0
     eval_batch_size: int = 8192
     max_hist: int = 32                 # svd++ implicit history length
@@ -126,8 +123,6 @@ class DPMFTrainer:
         with tracing.span("repro.trainer.init"):
             self.config = config
             self.opt = RowOptimizer(name=config.optimizer)
-            if config.epoch_mode not in ("scan", "python"):
-                raise ValueError(f"unknown epoch_mode {config.epoch_mode!r}")
             if config.objective not in ("explicit", "implicit", "bpr"):
                 raise ValueError(f"unknown objective {config.objective!r}")
             self._train_weight = None      # implicit confidence column
@@ -137,11 +132,6 @@ class DPMFTrainer:
                     raise ValueError(
                         "store-backed training supports only the explicit "
                         "objective"
-                    )
-                if config.epoch_mode != "scan":
-                    raise ValueError(
-                        f"objective {config.objective!r} requires "
-                        "epoch_mode='scan'"
                     )
                 if config.variant == "svdpp":
                     raise ValueError(
@@ -185,8 +175,6 @@ class DPMFTrainer:
                 # host memory is bounded by the queue depth, not the dataset.
                 from repro.store import RatingsStore, ShardedRatingsLoader
 
-                if config.epoch_mode != "scan":
-                    raise ValueError("store-backed training requires epoch_mode='scan'")
                 if config.variant == "svdpp":
                     raise ValueError(
                         "store-backed training does not support svdpp (the "
@@ -210,33 +198,32 @@ class DPMFTrainer:
                         slots=int(np.sum(self.hist < train_ds.num_items)),
                         truncated=truncated,
                     )
-            if config.epoch_mode == "scan":
-                # Upload the ratings (and eval set / SVD++ history) ONCE;
-                # per-epoch reshuffles happen on device (data/loader.py).  The
-                # batch size is clamped so a tiny dataset trains as one batch
-                # per epoch instead of degenerating to zero steps (which is
-                # what the drop-remainder host loop silently does).  In store
-                # mode the train table never lands on device wholesale.
-                self._packed_train = (
-                    loader.pack_ratings(
-                        train_ds,
-                        min(config.batch_size, max(len(train_ds), 1)),
-                        weight=self._train_weight,
-                    )
-                    if self._loader is None and config.objective != "bpr"
-                    else None
+            # Upload the ratings (and eval set / SVD++ history) ONCE;
+            # per-epoch reshuffles happen on device (data/loader.py).  The
+            # batch size is clamped so a tiny dataset trains as one batch
+            # per epoch instead of degenerating to zero steps (which is
+            # what a drop-remainder batch loop silently does).  In store
+            # mode the train table never lands on device wholesale.
+            self._packed_train = (
+                loader.pack_ratings(
+                    train_ds,
+                    min(config.batch_size, max(len(train_ds), 1)),
+                    weight=self._train_weight,
                 )
-                if config.objective == "bpr":
-                    from repro.workloads.bpr import BPRSampler
+                if self._loader is None and config.objective != "bpr"
+                else None
+            )
+            if config.objective == "bpr":
+                from repro.workloads.bpr import BPRSampler
 
-                    self._bpr_sampler = BPRSampler(
-                        train_ds, config.batch_size, seed=config.seed
-                    )
-                self._packed_eval = (
-                    loader.pack_eval_batches(test_ds, config.eval_batch_size)
-                    if test_ds is not None
-                    else None
+                self._bpr_sampler = BPRSampler(
+                    train_ds, config.batch_size, seed=config.seed
                 )
+            self._packed_eval = (
+                loader.pack_eval_batches(test_ds, config.eval_batch_size)
+                if test_ds is not None
+                else None
+            )
             self._hist_dev = None if self.hist is None else jnp.asarray(self.hist)
             self._packed_ranking = None
             if config.ranking_topk > 0 and test_ds is not None:
@@ -443,7 +430,6 @@ class DPMFTrainer:
                             self._hist_dev,
                             opt=self.opt,
                             lam=cfg.lam,
-                            use_fused_kernel=cfg.use_fused_kernel,
                         )
 
                     slab_start = time.perf_counter()
@@ -496,7 +482,7 @@ class DPMFTrainer:
                 )
                 jax.block_until_ready(self.params.p)
                 epoch_metrics = jax.device_get(metrics)
-            elif cfg.epoch_mode == "scan":
+            else:
                 # One donated, compiled computation for the whole epoch: on-device
                 # reshuffle, lax.scan of train_step, metrics summed on device.
                 with tracing.span("repro.trainer.shuffle"):
@@ -515,51 +501,11 @@ class DPMFTrainer:
                         self._hist_dev,
                         opt=self.opt,
                         lam=cfg.lam,
-                        use_fused_kernel=cfg.use_fused_kernel,
                     )
                 with tracing.span("repro.trainer.sync"):
                     jax.block_until_ready(self.params.p)
                     # the epoch's single host sync: a few scalars
                     epoch_metrics = jax.device_get(metrics)
-            else:
-                # Legacy per-batch loop.  Metrics accumulate as device scalars —
-                # fetched once after the loop, never per step (a float() here
-                # would serialize every dispatch on a host sync).
-                metric_sums = {
-                    "abs_err": jnp.zeros((), jnp.float32),
-                    "work_fraction": jnp.zeros((), jnp.float32),
-                }
-                steps = 0
-                for batch_np in loader.iterate_batches(
-                    self.train_ds,
-                    cfg.batch_size,
-                    seed=cfg.seed,
-                    epoch=self.epoch,
-                    hist=self.hist,
-                ):
-                    batch = {key: jnp.asarray(value) for key, value in batch_np.items()}
-                    self.params, self.opt_state, metrics = mf.train_step(
-                        self.params,
-                        self.opt_state,
-                        batch,
-                        t_p,
-                        t_q,
-                        lr,
-                        dim_mask,
-                        opt=self.opt,
-                        lam=cfg.lam,
-                        use_fused_kernel=cfg.use_fused_kernel,
-                    )
-                    metric_sums = {
-                        key: metric_sums.get(key, 0.0) + value
-                        for key, value in metrics.items()
-                    }
-                    steps += 1
-                jax.block_until_ready(self.params.p)
-                epoch_metrics = {
-                    key: float(value) / max(steps, 1)
-                    for key, value in jax.device_get(metric_sums).items()
-                }
             abs_err = float(epoch_metrics["abs_err"])
             work = float(epoch_metrics["work_fraction"])
             rows_shares = {
@@ -625,25 +571,9 @@ class DPMFTrainer:
             return float("nan")
         t_p = self.t_p if t_p is None else t_p
         t_q = self.t_q if t_q is None else t_q
-        if self.config.epoch_mode == "scan":
-            total, count = mf.eval_epoch_scan(
-                self.params, self._packed_eval, t_p, t_q, self._hist_dev
-            )
-            return float(total) / max(float(count), 1.0)
-        # Legacy loop: accumulate on device, fetch once at the end.
-        total = jnp.zeros((), jnp.float32)
-        count = jnp.zeros((), jnp.float32)
-        for batch_np in loader.iterate_batches(
-            self.test_ds,
-            self.config.eval_batch_size,
-            shuffle=False,
-            drop_remainder=False,
-            hist=self.hist,
-        ):
-            batch = {key: jnp.asarray(value) for key, value in batch_np.items()}
-            s, c = mf.eval_mae(self.params, batch, t_p, t_q)
-            total = total + s
-            count = count + c
+        total, count = mf.eval_epoch_scan(
+            self.params, self._packed_eval, t_p, t_q, self._hist_dev
+        )
         return float(total) / max(float(count), 1.0)
 
     def evaluate_ranking(self, t_p=None, t_q=None):

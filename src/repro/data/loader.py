@@ -67,8 +67,7 @@ def iterate_batches(
         }
         if not drop_remainder:
             # train batches (drop_remainder) are always full: omitting the
-            # all-ones weight keeps train_step's weight-free fast path (and
-            # the fused-kernel route) eligible
+            # all-ones weight keeps train_step's weight-free fast path
             batch["weight"] = weight
         if hist is not None:
             batch["hist"] = hist[ds.user[idx]]

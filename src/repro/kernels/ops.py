@@ -14,7 +14,6 @@ import jax.numpy as jnp
 
 from repro.core.ranks import effective_ranks, rank_mask
 from repro.kernels import ref
-from repro.kernels.fused_mf_sgd import fused_mf_sgd_padded
 from repro.kernels.pruned_matmul import pruned_matmul_padded
 from repro.kernels.pruned_topk import (
     TOPK_BLOCK_K,
@@ -230,76 +229,6 @@ def pruned_topk(
         interpret=interpret,
     )
     return scores[:m, :topk], idx[:m, :topk]
-
-
-def fused_mf_sgd(
-    p_rows: jax.Array,
-    q_rows: jax.Array,
-    ratings: jax.Array,
-    t_p: jax.Array | float,
-    t_q: jax.Array | float,
-    *,
-    lr: float,
-    lam: float,
-    bias_u: jax.Array | None = None,
-    bias_i: jax.Array | None = None,
-    global_mean: jax.Array | float = 0.0,
-    weight: jax.Array | None = None,
-    block_b: int = 256,
-    interpret: bool | None = None,
-    use_kernel: bool = True,
-):
-    """Fused Alg. 2 + Alg. 3 over a batch of gathered rows.
-
-    Returns ``(new_p_rows, new_q_rows, new_bias_u, new_bias_i, err)`` with
-    ``err`` shaped (B,); the bias outputs are None when the inputs are.
-    Optional per-row biases + global mean fold into the prediction (BiasSVD)
-    and an optional ``weight`` column gates the updates — both run inside
-    the kernel, so the biased/weighted cases share the fused path.
-    """
-    t_p = jnp.asarray(t_p, jnp.float32)
-    t_q = jnp.asarray(t_q, jnp.float32)
-    if not use_kernel:
-        return ref.fused_mf_sgd_ref(
-            p_rows, q_rows, ratings, t_p, t_q, lr=lr, lam=lam,
-            bias_u=bias_u, bias_i=bias_i, global_mean=global_mean,
-            weight=weight,
-        )
-    if interpret is None:
-        interpret = _default_interpret()
-    b = p_rows.shape[0]
-    has_bias = bias_u is not None
-
-    def col(v, fill):
-        full = jnp.full((b,), fill, jnp.float32) if v is None else v
-        return _pad_to(full.astype(jnp.float32)[:, None], block_b, 0)
-
-    pp = _pad_to(p_rows, block_b, 0)
-    qp = _pad_to(q_rows, block_b, 0)
-    rp = _pad_to(ratings[:, None].astype(jnp.float32), block_b, 0)
-    mu = jnp.asarray(global_mean if has_bias else 0.0, jnp.float32)
-    new_p, new_q, new_bu, new_bi, err = fused_mf_sgd_padded(
-        pp,
-        qp,
-        rp,
-        col(bias_u, 0.0),
-        col(bias_i, 0.0),
-        col(weight, 1.0),  # padding rows get weight 0 from _pad_to
-        t_p.reshape(1, 1),
-        t_q.reshape(1, 1),
-        mu.reshape(1, 1),
-        lr=lr,
-        lam=lam,
-        block_b=block_b,
-        interpret=interpret,
-    )
-    return (
-        new_p[:b],
-        new_q[:b],
-        new_bu[:b, 0] if has_bias else None,
-        new_bi[:b, 0] if has_bias else None,
-        err[:b, 0],
-    )
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k", "k"))

@@ -17,7 +17,7 @@ TPU adaptation of the paper's scalar early-exit (see DESIGN.md §2):
 Because Alg. 1 sorts the latent axis by joint sparsity, rank values are
 front-loaded and correlated, so the per-tile ``max`` stays close to individual
 ranks and tile-level skipping recovers most of the element-level savings
-(measured in benchmarks/bench_kernels.py).
+(``ops.tile_block_stats`` counts both).
 """
 from __future__ import annotations
 

@@ -77,7 +77,7 @@ def pruned_pair_dot_ref(
     return jnp.sum(pm * qm, axis=-1)
 
 
-def fused_mf_sgd_ref(
+def sgd_step_ref(
     p_rows: jax.Array,   # (b, k) gathered user factors
     q_rows: jax.Array,   # (b, k) gathered item factors
     ratings: jax.Array,  # (b,)
@@ -91,7 +91,9 @@ def fused_mf_sgd_ref(
     global_mean: jax.Array | float = 0.0,
     weight: jax.Array | None = None,   # (b,) update gate / importance weight
 ):
-    """Alg. 2 + Alg. 3 fused: masked dot, error, masked SGD row updates.
+    """One plain-SGD step over gathered rows (Alg. 2 + Alg. 3): masked dot,
+    error, masked row updates — the oracle of ``mf.train_step`` under
+    ``RowOptimizer("sgd")`` on a batch of distinct user and item ids.
 
     Returns ``(new_p_rows, new_q_rows, new_bias_u, new_bias_i, err)`` —
     the bias outputs are None when the inputs are.  Ranks are computed from

@@ -9,9 +9,9 @@ the production meshes and record memory/cost/collective statistics.
     PYTHONPATH=src python -m repro.launch.dryrun --arch fm --shape train_batch
     PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
 
-Results are cached as JSON under benchmarks/results/dryrun/ keyed by
-(arch, shape, mesh); EXPERIMENTS.md §Dry-run and §Roofline are generated from
-these files.
+Results are cached as JSON under ``<checkout>/.dryrun_cache/`` (created
+on demand, git-ignored) keyed by (arch, shape, mesh); EXPERIMENTS.md §Dry-run
+and §Roofline are generated from these files.
 """
 
 import argparse
@@ -26,7 +26,7 @@ from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.roofline import analysis
 
 RESULTS_DIR = os.path.join(
-    os.path.dirname(__file__), "..", "..", "..", "benchmarks", "results", "dryrun"
+    os.path.dirname(__file__), "..", "..", "..", ".dryrun_cache"
 )
 
 

@@ -39,11 +39,6 @@ def main() -> None:
     parser.add_argument("--optimizer", default="adagrad",
                         choices=["sgd", "momentum", "adagrad", "adadelta",
                                  "adam"])
-    parser.add_argument("--epoch-mode", default="scan",
-                        choices=["scan", "python"],
-                        help="scan: whole epoch as one donated lax.scan "
-                             "(device-resident data); python: legacy "
-                             "per-batch host loop")
     parser.add_argument("--strategy", default="standard",
                         choices=["standard", "twin"])
     parser.add_argument("--init", default="normal", choices=["normal", "uniform"])
@@ -59,7 +54,6 @@ def main() -> None:
                         help="implicit confidence c = 1 + alpha*r")
     parser.add_argument("--implicit-negatives", type=int, default=4,
                         help="sampled negatives per observed interaction")
-    parser.add_argument("--use-fused-kernel", action="store_true")
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--ckpt", default=None)
     parser.add_argument("--ckpt-every", type=int, default=5)
@@ -109,8 +103,6 @@ def main() -> None:
         objective=args.objective,
         implicit_alpha=args.implicit_alpha,
         implicit_negatives=args.implicit_negatives,
-        use_fused_kernel=args.use_fused_kernel,
-        epoch_mode=args.epoch_mode,
         seed=args.seed,
         checkpoint_dir=args.ckpt,
         checkpoint_every_epochs=args.ckpt_every,

@@ -14,7 +14,8 @@ cached aggregation vector only pays off if their next request lands on the
   the affinity map, so bulk/backfill traffic can neither evict
   interactive pins nor pollute replica caches with one-shot users.
 * ``policy="least"`` / ``policy="random"`` ignore affinity entirely —
-  the baselines ``benchmarks/bench_fleet.py`` compares against.
+  the baselines affinity routing is compared against
+  (``tests/test_fleet.py::test_fleet_affinity_warms_caches``).
 
 :class:`ServingFleet` is the one-call topology: N replicas (in-process or
 spawned) + a router, exposing ``submit``/``apply_update`` so it can be a

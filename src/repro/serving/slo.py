@@ -54,10 +54,8 @@ loop for pruned MF serving:
   (:meth:`repro.serving.fleet.router.Router.apply_thresholds`) — exactly
   the discipline model refreshes use.
 
-``benchmarks/bench_slo.py`` maps the resulting throughput/NDCG@K frontier
-and replays an overload scenario; ``launch/serve.py --slo-p99-ms`` turns
-the loop on for real traffic and exits non-zero if the budget is violated
-at steady state.
+``launch/serve.py --slo-p99-ms`` turns the loop on for real traffic and
+exits non-zero if the budget is violated at steady state.
 """
 from __future__ import annotations
 

@@ -18,7 +18,8 @@ the slab size instead:
   slab from the store and ``jax.device_put``s it while the training scan
   consumes the current one, so host→device transfer overlaps compute.
   Peak host memory is ``O(prefetch * slab_steps * B)``, independent of the
-  dataset size (asserted by ``benchmarks/bench_scale.py``).
+  dataset size (``tests/test_store.py`` pins the worker to the queue
+  depth).
 
 Determinism contract: for a given ``(seed, epoch)`` the *set* of examples
 an epoch visits and their batch assignment are fixed; resuming from slab

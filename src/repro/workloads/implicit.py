@@ -9,12 +9,12 @@ plays, purchases).  The WALS formulation trains on *binary preference*
 
 That is exactly the weighted least-squares objective the existing stack
 already speaks: the binary preference becomes the ``rating`` column and the
-confidence becomes the ``batch["weight"]`` gate of ``mf.train_step`` /
-``fused_mf_sgd`` — the weight scales the update (and metrics), never the
-prediction, which is precisely the WALS gradient ``c_ui·err·y_i``.  So the
-implicit objective flows through ``train_epoch_scan``, the fused Pallas
-kernel, and the ``OnlineUpdater`` *unchanged*; this module only owns the
-data transformation (positives + sampled negatives + confidence column).
+confidence becomes the ``batch["weight"]`` gate of ``mf.train_step`` —
+the weight scales the update (and metrics), never the prediction, which is
+precisely the WALS gradient ``c_ui·err·y_i``.  So the implicit objective
+flows through ``train_epoch_scan`` and the ``OnlineUpdater`` *unchanged*;
+this module only owns the data transformation (positives + sampled
+negatives + confidence column).
 
 Unobserved (user, item) pairs are weak negatives: preference 0 at the floor
 confidence 1.  Training on every unobserved cell is O(m·n), so — as in

@@ -2,6 +2,7 @@
 holdouts), exact engine/oracle parity at threshold 0 on every serving path
 (streaming, kernel, sharded), and the one-scan epoch variant."""
 import math
+import types
 
 import numpy as np
 import jax
@@ -221,6 +222,29 @@ def test_pruned_engine_still_matches_pruned_oracle():
     got = R.evaluate_engine(engine, ds, topk=10)
     want = R.evaluate_oracle(params, ds, topk=10, t_p=t, t_q=t)
     assert got == want
+
+
+def test_dense_own_top_l_relevance_scores_perfect():
+    """Relevance = each user's own dense top-L: the dense oracle scores a
+    perfect HR and recall, and the engine at threshold 0 scores exactly
+    the same — so against this relevance a pruned engine's shortfall is
+    the ranking distortion of pruning alone."""
+    m, n, k, rel_l = 64, 500, 16, 5
+    params = mf.init_params(jax.random.PRNGKey(0), m, n, k,
+                            init_method="libmf")
+    users = np.arange(m, dtype=np.int32)
+    _, top_l = R.dense_topk(params, users, rel_l)
+    holdout = types.SimpleNamespace(
+        user=np.repeat(users, rel_l),
+        item=np.asarray(top_l).reshape(-1),
+        rating=np.ones(m * rel_l, np.float32),
+    )
+    relevance = R.relevance_from_dataset(holdout)
+    engine = ServingEngine(params, 0.0, 0.0, use_kernel=False, max_batch=32)
+    oracle = R.evaluate_oracle(params, topk=10, relevance=relevance)
+    assert oracle.hr == oracle.recall == 1.0, oracle
+    assert oracle.ndcg == pytest.approx(1.0)
+    assert R.evaluate_engine(engine, topk=10, relevance=relevance) == oracle
 
 
 # ---------------------------------------------------------------------------

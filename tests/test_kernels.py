@@ -1,12 +1,15 @@
 """Per-kernel validation: shape/dtype sweeps against the pure-jnp oracles
-(interpret mode executes the Pallas kernel bodies on CPU)."""
+(interpret mode executes the Pallas kernel bodies on CPU), and the training
+step under plain SGD against its one-step oracle."""
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core.ranks import effective_ranks
-from repro.kernels import fused_mf_sgd, pruned_matmul, ref, tile_block_stats
+from repro.kernels import pruned_matmul, ref, tile_block_stats
+
+from sgd_step import train_step_on_rows, weighted_mean_abs
 
 
 def _factors(m, n, k, dtype, seed=0, scale=0.1):
@@ -40,25 +43,31 @@ def test_pruned_matmul_vs_ref(m, n, k, bm, bn, bk, dtype, t):
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("b,k,bb", [(64, 32, 16), (33, 50, 8), (7, 16, 16), (256, 128, 128)])
+@pytest.mark.parametrize("b,k", [(64, 32), (33, 50), (7, 16), (256, 128)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("t", [0.0, 0.06])
-def test_fused_mf_sgd_vs_ref(b, k, bb, dtype, t):
+def test_sgd_step_vs_ref(b, k, dtype, t):
+    """The training step under plain SGD (Algs. 2 + 3 on the gathered rows)
+    matches the one-step oracle, rows and error."""
     rng = np.random.default_rng(1)
     p = jnp.asarray(rng.normal(0, 0.1, (b, k)).astype(np.float32), dtype)
     q = jnp.asarray(rng.normal(0, 0.1, (b, k)).astype(np.float32), dtype)
     r = jnp.asarray(rng.uniform(1, 5, (b,)).astype(np.float32))
-    exp_p, exp_q, _, _, exp_e = ref.fused_mf_sgd_ref(
+    exp_p, exp_q, _, _, exp_e = ref.sgd_step_ref(
         p, q, r, jnp.float32(t), jnp.float32(t), lr=0.05, lam=0.02
     )
-    got_p, got_q, got_bu, got_bi, got_e = fused_mf_sgd(
-        p, q, r, t, t, lr=0.05, lam=0.02, block_b=bb
+    got_p, got_q, got_bu, got_bi, got_err = train_step_on_rows(
+        p, q, r, t, lr=0.05, lam=0.02
     )
-    assert got_bu is None and got_bi is None  # unbiased call
+    assert got_bu is None and got_bi is None  # unbiased model
+    assert got_p.dtype == dtype and got_q.dtype == dtype
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(got_p), np.asarray(exp_p), rtol=tol, atol=tol)
-    np.testing.assert_allclose(np.asarray(got_q), np.asarray(exp_q), rtol=tol, atol=tol)
-    np.testing.assert_allclose(np.asarray(got_e), np.asarray(exp_e), rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(got_p, np.float32),
+                               np.asarray(exp_p, np.float32), rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(got_q, np.float32),
+                               np.asarray(exp_q, np.float32), rtol=tol, atol=tol)
+    np.testing.assert_allclose(float(got_err), float(weighted_mean_abs(exp_e)),
+                               rtol=tol, atol=tol)
 
 
 def test_kernel_under_jit_grad_free():

@@ -351,13 +351,7 @@ def test_online_freshness_end_to_end():
     ds = synthetic_ratings(200, 300, 15000, seed=0)
     rest, test_ds = train_test_split(ds, 0.2, seed=0)
     train_ds, stream_ds = train_test_split(rest, 0.25, seed=1)
-    # epoch_mode="python" pins the host-loader data order this test's tight
-    # 5% retrain-vs-online margin was calibrated against: at this toy scale
-    # the pruning-threshold calibration after epoch 1 is sensitive to the
-    # shuffle order, and the scan path draws a different (equally valid)
-    # permutation.  The online subsystem under test is order-independent.
-    cfg = TrainConfig(k=16, epochs=4, batch_size=1024, pruning_rate=0.3,
-                      epoch_mode="python")
+    cfg = TrainConfig(k=16, epochs=4, batch_size=1024, pruning_rate=0.3)
 
     retrain = DPMFTrainer(cfg, _concat(train_ds, stream_ds), test_ds)
     retrain.run()

@@ -111,13 +111,14 @@ def test_threshold_matches_empirical_fraction():
 @given(pq_strategy(), st.floats(0.01, 0.2), st.floats(0.01, 0.1), st.floats(0.0, 0.1))
 @settings(max_examples=25, deadline=None)
 def test_fused_sgd_matches_paper_update_loop(pq, t, lr, lam):
-    """fused ref == Algorithm 3's truncated scalar update, pair by pair."""
+    """The one-step SGD oracle == Algorithm 3's truncated scalar update,
+    pair by pair."""
     p, q = pq
     n_pairs = min(p.shape[0], q.shape[0], 5)
     p_rows = p[:n_pairs]
     q_rows = q[:n_pairs]
     ratings = np.linspace(1, 5, n_pairs).astype(np.float32)
-    new_p, new_q, _, _, err = ref.fused_mf_sgd_ref(
+    new_p, new_q, _, _, err = ref.sgd_step_ref(
         jnp.asarray(p_rows), jnp.asarray(q_rows), jnp.asarray(ratings),
         jnp.float32(t), jnp.float32(t), lr=lr, lam=lam,
     )

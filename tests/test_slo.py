@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.core import mf
 from repro.core.threshold import (
@@ -314,6 +315,41 @@ def test_maybe_tick_rate_limits():
     assert ctl.maybe_tick() is not None
     assert ctl.maybe_tick() is None  # 30s have not elapsed
     assert ctl.ticks == 1
+
+
+# ---------------------------------------------------------------------------
+# the quality frontier the controller walks
+# ---------------------------------------------------------------------------
+
+
+def test_pruning_harder_never_raises_ndcg():
+    """Along the rate ladder the controller degrades through, top-10 NDCG
+    against the dense ranking never rises, and rate 0 is exactly the dense
+    ranking.  The factors have a decaying latent spectrum, as trained
+    factors do, so pruning evicts the low-energy tail first."""
+    m, n, k, topk = 64, 500, 16, 10
+    base = mf.init_params(jax.random.PRNGKey(0), m, n, k)
+    scale = jnp.asarray(0.97 ** np.arange(k), jnp.float32)[None, :]
+    params = base._replace(p=base.p * scale, q=base.q * scale)
+    users = np.random.default_rng(0).integers(0, m, 32)
+    scores = np.asarray(params.p[users] @ params.q.T)
+    dense_idx = np.argsort(-scores, axis=1, kind="stable")[:, :topk]
+    discounts = 1.0 / np.log2(np.arange(2, topk + 2))
+    sp, sq = measure_stats(params.p), measure_stats(params.q)
+    ndcgs = []
+    for rate in (0.0, 0.12, 0.35):
+        engine = ServingEngine(params, threshold_for_rate(sp, rate),
+                               threshold_for_rate(sq, rate),
+                               use_kernel=False, max_batch=32)
+        _, idx = engine.topk(users, topk)
+        idx = np.asarray(idx)
+        if rate == 0.0:
+            np.testing.assert_array_equal(idx, dense_idx)
+        hits = np.stack([np.isin(a, b) for a, b in zip(idx, dense_idx)])
+        ndcgs.append(float(np.mean(hits @ discounts) / discounts.sum()))
+    assert ndcgs[0] == 1.0
+    assert all(hi <= lo + 1e-9 for lo, hi in zip(ndcgs, ndcgs[1:])), ndcgs
+    assert ndcgs[-1] < 1.0, ndcgs
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,7 @@ order is a pure function of ``(n, seed, epoch)`` and slab boundaries never
 change what an example's batch assignment is.
 """
 import os
+import time
 
 import numpy as np
 import jax
@@ -186,6 +187,31 @@ def test_loader_early_close_shuts_down_worker(tmp_path):
     assert threading_active_prefetchers() <= before + 0
 
 
+def test_loader_runs_at_most_queue_depth_ahead(tmp_path):
+    """The prefetch depth, not the data set, bounds what the loader holds:
+    while the consumer holds slab s, the worker has built slabs up to
+    s + prefetch + 1 (prefetch queued, one waiting to be put) and no
+    further."""
+    store = RatingsStore(build_store(_ds(), str(tmp_path / "s")))
+    loader = ShardedRatingsLoader(store, 32, slab_steps=4, prefetch=2)
+    assert loader.num_slabs > loader.prefetch + 2
+    built = []
+    host_slab = loader._host_slab
+
+    def recording(perm, slab_idx):
+        built.append(slab_idx)
+        return host_slab(perm, slab_idx)
+
+    loader._host_slab = recording
+    for slab in loader.epoch_slabs(0, 0):
+        ahead = min(slab.slab_idx + loader.prefetch + 1, loader.num_slabs - 1)
+        deadline = time.monotonic() + 10.0
+        while max(built) < ahead and time.monotonic() < deadline:
+            time.sleep(0.005)
+        time.sleep(0.02)   # room for a worker that ignored the bound
+        assert max(built) == ahead, (slab.slab_idx, built)
+
+
 def threading_active_prefetchers():
     import threading
 
@@ -295,15 +321,6 @@ def test_store_trainer_matches_metadata(tmp_path):
     trainer.run_epoch()
     assert len(trainer.history) == 1
     assert np.isfinite(trainer.history[-1].train_abs_err)
-
-
-def test_store_trainer_requires_scan_mode(tmp_path):
-    ds = _ds(256, 30, 20)
-    store_dir = build_store(ds, str(tmp_path / "store"))
-    cfg = TrainConfig(k=4, epochs=1, batch_size=32, store_dir=store_dir,
-                      epoch_mode="python")
-    with pytest.raises(ValueError, match="scan"):
-        DPMFTrainer(cfg)
 
 
 # ---------------------------------------------------------------------------

@@ -3,7 +3,13 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core import DPMFTrainer, TrainConfig, percentage_mae, work_speedup
+from repro.core import (
+    DPMFTrainer,
+    TrainConfig,
+    percentage_mae,
+    sparsity_per_dim,
+    work_speedup,
+)
 from repro.data import paper_dataset, synthetic_ratings, train_test_split
 
 
@@ -54,9 +60,8 @@ def test_pruned_training_full_pipeline(movielens_small):
     assert sorted(np.asarray(pruned.perm).tolist()) == list(range(24))
 
     # Bounded error increase.  The paper's <=20% P_MAE regime needs the LibMF
-    # protocol (non-negative init, convergence-level epochs) — covered by
-    # benchmarks/bench_paper_figures.fig11; this quick test uses zero-mean
-    # init at 8 epochs where truncation costs more.
+    # protocol (non-negative init, convergence-level epochs); this quick test
+    # uses zero-mean init at 8 epochs where truncation costs more.
     pmae = percentage_mae(pruned.history[-1].test_mae, dense.history[-1].test_mae)
     assert pmae < 100.0, f"error blow-up: {pmae}%"
 
@@ -106,15 +111,24 @@ def test_hyperparameter_agnostic(movielens_small, overrides):
     assert np.isfinite(trainer.history[-1].test_mae)
 
 
-def test_fused_kernel_training_path(movielens_small):
-    """FunkSVD+SGD routed through the fused Pallas kernel (interpret mode)
-    trains to a comparable MAE as the XLA path."""
-    train_ds, test_ds = movielens_small
-    xla = _run(train_ds, test_ds, epochs=3, pruning_rate=0.3, lr=0.005,
-               optimizer="sgd", use_fused_kernel=False)
-    pal = _run(train_ds, test_ds, epochs=3, pruning_rate=0.3, lr=0.005,
-               optimizer="sgd", use_fused_kernel=True)
-    assert abs(xla.history[-1].test_mae - pal.history[-1].test_mae) < 0.05
+def test_sparsity_decreases_with_training(movielens_small):
+    """Paper Fig. 5: the share of insignificant factors per latent
+    dimension does not grow as training goes on, so the latent order the
+    one-time rearrangement fixes after epoch 1 stays valid."""
+    train_ds, _ = movielens_small
+    trainer = DPMFTrainer(
+        TrainConfig(k=24, epochs=5, batch_size=2048, seed=0), train_ds
+    )
+    sparsity = []
+    for _ in range(5):
+        trainer.run_epoch()
+        sparsity.append([
+            float(jnp.mean(sparsity_per_dim(table, 0.06)))
+            for table in (trainer.params.p, trainer.params.q)
+        ])
+    (p_first, q_first), (p_last, q_last) = sparsity[0], sparsity[-1]
+    assert p_first >= p_last - 0.05, sparsity
+    assert q_last < q_first, sparsity
 
 
 def test_paper_dataset_shapes():

@@ -24,7 +24,6 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.core import mf
-from repro.kernels.fused_mf_sgd import fused_mf_sgd_padded
 from repro.kernels.pruned_matmul import pruned_matmul_padded
 from repro.kernels.pruned_topk import pruned_topk_padded
 from repro.kernels.row_write import write_rows
@@ -113,18 +112,6 @@ def test_pruned_matmul_compiles(one_chip):
     assert _has_kernel(compiled)
 
 
-def test_fused_mf_sgd_compiles(one_chip):
-    b = 65_536
-    rows = _sds((b, K), jnp.float32, one_chip)
-    col = _sds((b, 1), jnp.float32, one_chip)
-    scalar = _sds((1, 1), jnp.float32, one_chip)
-    compiled = fused_mf_sgd_padded.lower(
-        rows, rows, col, col, col, col, scalar, scalar, scalar,
-        lr=0.05, lam=0.02, interpret=False,
-    ).compile()
-    assert _has_kernel(compiled)
-
-
 @pytest.mark.parametrize(
     "rows", [162_541, 62_423], ids=["ml25m_users", "ml25m_items"]
 )
@@ -141,12 +128,16 @@ def test_write_rows_compiles(one_chip, rows):
     assert _has_kernel(compiled)
 
 
-def test_train_epoch_scan_fused_compiles(one_chip):
-    """The trainer's whole epoch program (SGD + fused kernel) at the
-    Netflix shape: the kernel inside the donated scan, and the tables fit."""
+def test_train_epoch_scan_compiles(one_chip, monkeypatch):
+    """The training cells' epoch program (FunkSVD, Adagrad) at the Netflix
+    shape: the row-write kernel inside the donated scan, once for P and
+    once for Q, and the tables fit.  ``write_rows`` picks the kernel by
+    backend, which is the CPU here: the test steers it to the chip's
+    branch."""
     m, n = NETFLIX
     steps, batch = 8, 4096
-    opt = RowOptimizer(name="sgd")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    opt = RowOptimizer(name="adagrad")
     params = jax.eval_shape(
         functools.partial(mf.init_params, num_users=m, num_items=n, k=K),
         jax.random.PRNGKey(0),
@@ -162,9 +153,11 @@ def test_train_epoch_scan_fused_compiles(one_chip):
     compiled = mf.train_epoch_scan.lower(
         _abstract(params, place), _abstract(state, place), batches,
         scalar, scalar, scalar, _sds((K,), jnp.float32, one_chip), None,
-        opt=opt, lam=0.02, use_fused_kernel=True, interpret=False,
+        opt=opt, lam=0.02,
     ).compile()
-    assert _has_kernel(compiled)
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
 def test_train_epoch_scan_svdpp_compiles(one_chip, monkeypatch):
@@ -193,7 +186,7 @@ def test_train_epoch_scan_svdpp_compiles(one_chip, monkeypatch):
         _abstract(params, place), _abstract(state, place), batches,
         scalar, scalar, scalar, _sds((K,), jnp.float32, one_chip),
         _sds((m, hist_len), jnp.int32, one_chip),
-        opt=opt, lam=0.02, interpret=False,
+        opt=opt, lam=0.02,
     ).compile()
     # the row-DMA kernel writes P, Q and Y; the bias columns go by scatter
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 3

@@ -3,8 +3,9 @@
 The contract under test: ``mf.train_epoch_scan`` (one donated lax.scan per
 epoch over packed device-resident batches) is *numerically equivalent* to
 folding ``mf.train_step`` over the same batches from Python — for every row
-optimizer, every variant, and the weighted/biased fused-kernel cases — and
-the donation never lets stale buffers leak back into the caller.
+optimizer and every variant — the step itself matches the one-step SGD
+oracle on biased and weighted batches, and the donation never lets stale
+buffers leak back into the caller.
 """
 import numpy as np
 import jax
@@ -14,9 +15,11 @@ import pytest
 from repro.core import mf
 from repro.data import build_user_history, synthetic_ratings
 from repro.data import loader
-from repro.kernels import fused_mf_sgd, ref
+from repro.kernels import ref
 from repro.launch.mesh import make_mesh
 from repro.optim.optimizers import RowOptimizer
+
+from sgd_step import train_step_on_rows, weighted_mean_abs
 
 OPTIMIZERS = ("sgd", "momentum", "adagrad", "adadelta", "adam")
 # momentum compounds duplicate-row updates; a smaller lr keeps it stable
@@ -33,7 +36,7 @@ def packed():
 
 
 def _fold_train_step(params, state, batches, *, opt, hist=None, t=0.04,
-                     lr=0.05, use_fused_kernel=False):
+                     lr=0.05):
     steps = batches["user"].shape[0]
     errs, works = [], []
     for i in range(steps):
@@ -43,7 +46,6 @@ def _fold_train_step(params, state, batches, *, opt, hist=None, t=0.04,
         params, state, m = mf.train_step(
             params, state, b, jnp.float32(t), jnp.float32(t),
             jnp.float32(lr), jnp.ones((K,)), opt=opt, lam=0.02,
-            use_fused_kernel=use_fused_kernel,
         )
         errs.append(float(m["abs_err"]))
         works.append(float(m["work_fraction"]))
@@ -134,9 +136,9 @@ def test_scan_epoch_weighted_batches(packed):
 
 
 @pytest.mark.parametrize("weighted", [False, True])
-def test_fused_kernel_bias_weight_vs_ref(weighted):
-    """The generalized kernel (biases + weight in-kernel, interpret mode)
-    matches the pure-jnp reference bit-for-bit at f32."""
+def test_sgd_step_bias_weight_vs_ref(weighted):
+    """The step on a BiasSVD model, weighted or not, matches the one-step
+    SGD oracle with biases, the global mean and the weight column."""
     rng = np.random.default_rng(2)
     b, k = 96, 24
     p = jnp.asarray(rng.normal(0, 0.1, (b, k)).astype(np.float32))
@@ -150,35 +152,18 @@ def test_fused_kernel_bias_weight_vs_ref(weighted):
     )
     kw = dict(lr=0.05, lam=0.02, bias_u=bu, bias_i=bi, global_mean=3.1,
               weight=w)
-    want = ref.fused_mf_sgd_ref(p, q, r, jnp.float32(0.06), jnp.float32(0.06),
-                                **kw)
-    got = fused_mf_sgd(p, q, r, 0.06, 0.06, block_b=32, **kw)
-    for name, a, b_ in zip(("p", "q", "bu", "bi", "err"), want, got):
+    *want, want_err = ref.sgd_step_ref(
+        p, q, r, jnp.float32(0.06), jnp.float32(0.06), **kw
+    )
+    *got, got_abs_err = train_step_on_rows(p, q, r, 0.06, **kw)
+    for name, a, b_ in zip(("p", "q", "bu", "bi"), want, got):
         np.testing.assert_allclose(
             np.asarray(b_), np.asarray(a), atol=1e-6, rtol=0, err_msg=name
         )
-
-
-def test_fused_train_step_biased_weighted_matches_xla(packed):
-    """use_fused_kernel=True now covers BiasSVD and weighted batches."""
-    opt = RowOptimizer(name="sgd")
-    batches = dict(packed.epoch_batches(0, 2))
-    rng = np.random.default_rng(1)
-    batches["weight"] = jnp.asarray(
-        rng.uniform(0.0, 1.0, batches["rating"].shape).astype(np.float32)
+    np.testing.assert_allclose(
+        float(got_abs_err), float(weighted_mean_abs(want_err, w)),
+        atol=1e-6, rtol=0,
     )
-    b = {key: v[0] for key, v in batches.items()}
-    params, state = _fresh(opt, "bias")
-    args = (jnp.float32(0.04), jnp.float32(0.04), jnp.float32(0.02),
-            jnp.ones((K,)))
-    want_p, _, want_m = mf.train_step(
-        params, state, b, *args, opt=opt, lam=0.02, use_fused_kernel=False
-    )
-    got_p, _, got_m = mf.train_step(
-        params, state, b, *args, opt=opt, lam=0.02, use_fused_kernel=True
-    )
-    _assert_params_close(want_p, got_p)
-    assert abs(float(want_m["abs_err"]) - float(got_m["abs_err"])) < 1e-5
 
 
 def test_donation_safety(packed):

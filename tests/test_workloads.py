@@ -9,8 +9,8 @@ paper defines:
 * the NumPy transcription ``kernels.ref.bpr_step_ref`` on 1/8-grid
   factors — framework-independent semantics, scatter-add duplicates and
   all;
-* the fused Pallas kernel vs the masked XLA formulation for the
-  confidence-weighted objective.
+* the one-step SGD oracle ``kernels.ref.sgd_step_ref`` with the
+  confidence as its weight column, for the confidence-weighted objective.
 
 Plus hypothesis property tests on the WALS confidence contract: weight 0
 is bitwise inert, larger confidence moves factors further.
@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st  # noqa: F401
+from sgd_step import train_step_on_rows, weighted_mean_abs
 
 from repro.core import mf
 from repro.core.ranks import effective_ranks, rank_mask
@@ -327,32 +328,33 @@ def test_bpr_threshold_zero_is_dense():
     assert float(metrics["work_fraction"]) == 1.0
 
 
-# -- oracle 3: fused Pallas kernel vs masked XLA, weighted objective -------
+# -- oracle 3: the one-step SGD oracle, weighted objective ----------------
 
 @pytest.mark.parametrize("variant", ["funk", "bias"])
-def test_fused_kernel_matches_xla_for_implicit_objective(variant):
-    """The confidence-weighted (implicit) batch takes the fused-kernel SGD
-    path; it must match the masked XLA formulation."""
+def test_implicit_confidence_step_matches_sgd_ref(variant):
+    """The confidence-weighted (implicit) batch through the SGD step matches
+    the one-step oracle with the confidence as its weight column."""
     params = _grid_params(51, variant)
-    opt = RowOptimizer(name="sgd")
-    state = mf.init_opt_state(params, opt)
-    batch = _weighted_batch(52)
-    args = (*ARGS, jnp.float32(0.05), jnp.ones((K,)))
-    want, _, want_m = mf.train_step(
-        params, state, batch, *args, opt=opt, lam=LAM, use_fused_kernel=False
-    )
-    got, _, got_m = mf.train_step(
-        params, state, batch, *args, opt=opt, lam=LAM, use_fused_kernel=True
-    )
-    for name in ("p", "q", "user_bias", "item_bias"):
-        a, b = getattr(want, name), getattr(got, name)
+    rng = np.random.default_rng(52)
+    b = 16
+    r = jnp.asarray(rng.integers(0, 2, b).astype(np.float32))
+    c = jnp.asarray(confidence_weights(rng.integers(0, 2, b), alpha=4.0))
+    kw = dict(lr=LR, lam=LAM, weight=c)
+    if variant == "bias":
+        kw.update(bias_u=params.user_bias[:b, 0],
+                  bias_i=params.item_bias[:b, 0],
+                  global_mean=params.global_mean)
+    p, q = params.p[:b], params.q[:b]
+    *want, want_err = ref.sgd_step_ref(p, q, r, *ARGS, **kw)
+    *got, got_abs_err = train_step_on_rows(p, q, r, ARGS[0], **kw)
+    for name, a, g in zip(("p", "q", "user_bias", "item_bias"), want, got):
         if a is None:
-            assert b is None
+            assert g is None
             continue
         np.testing.assert_allclose(
-            np.asarray(b), np.asarray(a), rtol=0, atol=1e-6, err_msg=name
+            np.asarray(g), np.asarray(a), rtol=0, atol=1e-6, err_msg=name
         )
-    assert abs(float(want_m["abs_err"]) - float(got_m["abs_err"])) < 1e-5
+    assert abs(float(got_abs_err) - float(weighted_mean_abs(want_err, c))) < 1e-5
 
 
 # -- hypothesis: the confidence-weight contract ----------------------------
@@ -576,10 +578,6 @@ def test_trainer_objective_validation():
     tr, te = _split()
     with pytest.raises(ValueError, match="unknown objective"):
         DPMFTrainer(TrainConfig(objective="pointwise"), tr, te)
-    with pytest.raises(ValueError, match="scan"):
-        DPMFTrainer(
-            TrainConfig(objective="implicit", epoch_mode="python"), tr, te
-        )
     with pytest.raises(ValueError, match="svdpp"):
         DPMFTrainer(TrainConfig(objective="bpr", variant="svdpp"), tr, te)
     with pytest.raises(ValueError, match="train_ds"):
