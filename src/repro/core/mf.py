@@ -14,14 +14,16 @@ API applies uniformly.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.ranks import effective_ranks, rank_mask
 from repro.kernels import ops as kops
-from repro.optim.optimizers import RowOptimizer, add_rows, rows_written
+from repro.optim.optimizers import RowOptimizer, _run_sums, add_rows, rows_written
 
 Batch = Dict[str, jax.Array]
 
@@ -197,6 +199,66 @@ def init_opt_state(params: MFParams, opt: RowOptimizer) -> MFOptState:
     )
 
 
+def implicit_capacity(sizes, batch: int, max_hist: int) -> int:
+    """How many history slots a step's implicit side runs on.
+
+    ``sizes`` holds |N(u)| of the user of each rating a step may hold.  A
+    step of ``batch`` such ratings has ``batch * max_hist`` slots, and
+    ``batch * mean`` live ones on average with a standard deviation of
+    ``sqrt(batch) * std``; the capacity is the smallest
+    ``batch * max_hist / 2**j`` that holds six standard deviations more
+    than the mean.  A step with more live slots than the capacity still
+    trains exactly, on all of them (:func:`_train_step`)."""
+    sizes = np.asarray(sizes, np.float64)
+    need = batch * sizes.mean() + 6.0 * math.sqrt(batch) * sizes.std()
+    capacity = batch * max_hist
+    while capacity % 2 == 0 and capacity // 2 >= need:
+        capacity //= 2
+    return capacity
+
+
+class _Slots(NamedTuple):
+    """A step's live history slots, packed in rating order into the first
+    ``count`` of ``capacity`` positions; the positions past it are dead."""
+
+    item: jax.Array     # (capacity,) item id; num_items (Y's zero row) if dead
+    rating: jax.Array   # (capacity,) the rating whose history holds the slot
+    head: jax.Array     # (capacity,) True at a rating's first slot, and if dead
+    live: jax.Array     # (capacity,) bool
+    last: jax.Array     # (B,) position of each rating's last slot
+    count: jax.Array    # () live slots in the step
+
+
+def _compact_slots(hist: jax.Array, n_items: int, capacity: int) -> _Slots:
+    """Pack the live slots (ids below ``n_items``) of the (B, H) ``hist``
+    into ``capacity`` positions, in the order of ``hist.reshape(-1)``.  A
+    live slot may sit anywhere in its row; the packing keeps only the first
+    ``capacity`` of them, so the caller checks ``count`` against it."""
+    slots_per_rating = hist.shape[1]
+    flat = hist.reshape(-1)
+    pos = jnp.arange(flat.size, dtype=jnp.int32)
+    # one sort of unique keys puts the live positions first, each group in
+    # flat order (a binary search of the live count's cumsum instead costs
+    # a scalar gather per level, ~17 of them, on the chip)
+    key, item = jax.lax.sort(
+        (jnp.where(flat < n_items, pos, pos + flat.size), flat), num_keys=1
+    )
+    key, item = key[:capacity], item[:capacity]
+    # live slots up to and including each rating's history
+    ends = jnp.cumsum(jnp.sum((hist < n_items).astype(jnp.int32), axis=1))
+    live = jnp.arange(capacity, dtype=jnp.int32) < ends[-1]
+    rating = jnp.minimum(key, flat.size - 1) // slots_per_rating
+    head = jnp.concatenate([jnp.ones((1,), bool), rating[1:] != rating[:-1]])
+    return _Slots(
+        item=item,
+        rating=rating,
+        head=head | ~live,
+        live=live,
+        last=ends - 1,
+        count=ends[-1],
+    )
+
+
 def _train_step(
     params: MFParams,
     opt_state: MFOptState,
@@ -208,6 +270,7 @@ def _train_step(
     *,
     opt: RowOptimizer,
     lam: float,
+    capacity: Optional[int] = None,
 ) -> Tuple[MFParams, MFOptState, Dict[str, jax.Array]]:
     """One minibatched, dynamically-pruned MF update (Algs. 2 + 3).
 
@@ -226,13 +289,75 @@ def _train_step(
     (momentum/adadelta/adam) a zero-weight row still *writes back* its
     row's decayed state, the same caveat duplicate rows already carry —
     which is why the online updater chunks instead of padding.
+
+    SVD++ runs Y's gather, sum and update on the step's live history slots
+    packed into ``capacity`` positions (:func:`implicit_capacity`; all B*H
+    slots where it is None), and into all B*H positions in a step that has
+    more live slots than that; the step metric ``implicit_compact_share``
+    is 1 for a step packed below B*H and 0 otherwise.
     """
-    u, i, r = batch["user"], batch["item"], batch["rating"].astype(jnp.float32)
     hist = batch.get("hist")
+    step = functools.partial(
+        _masked_step, params, opt_state, batch, t_p, t_q, lr, dim_mask,
+        opt=opt, lam=lam,
+    )
+    if params.implicit is None or hist is None:
+        return step()
+    n_items = params.implicit.shape[0] - 1
+
+    def every_slot():
+        return _with_compact_share(step(_compact_slots(hist, n_items, hist.size)), 0.0)
+
+    if capacity is None or capacity >= hist.size:
+        return every_slot()
+    slots = _compact_slots(hist, n_items, capacity)
+    return jax.lax.cond(
+        slots.count <= capacity,
+        lambda: _with_compact_share(step(slots), 1.0),
+        every_slot,
+    )
+
+
+def _with_compact_share(out, share: float):
+    new_params, new_state, metrics = out
+    return new_params, new_state, dict(
+        metrics, implicit_compact_share=jnp.float32(share)
+    )
+
+
+def _masked_step(
+    params: MFParams,
+    opt_state: MFOptState,
+    batch: Batch,
+    t_p: jax.Array,
+    t_q: jax.Array,
+    lr: jax.Array,
+    dim_mask: jax.Array,
+    slots: Optional[_Slots] = None,
+    *,
+    opt: RowOptimizer,
+    lam: float,
+) -> Tuple[MFParams, MFOptState, Dict[str, jax.Array]]:
+    """:func:`_train_step`'s update; SVD++'s implicit side runs on the
+    packed history ``slots`` of ``batch["hist"]``."""
+    u, i, r = batch["user"], batch["item"], batch["rating"].astype(jnp.float32)
     weight = batch.get("weight")
     k = params.p.shape[-1]
 
-    pu = _user_vector(params, u, hist)
+    pu = params.p[u]
+    if slots is not None:
+        # z_u = p_u + |N(u)|^-1/2 sum_{j in N(u)} y_j, the sum being each
+        # rating's run of packed rows read at its last slot; a rating with
+        # no history reads 0
+        hist = batch["hist"]
+        n_items = params.implicit.shape[0] - 1
+        counts = jnp.sum((hist < n_items).astype(jnp.float32), axis=1, keepdims=True)
+        y_rows = params.implicit[slots.item]
+        y_sum = jnp.where(
+            counts > 0, _run_sums(y_rows, slots.head)[jnp.maximum(slots.last, 0)], 0.0
+        )
+        pu = pu + y_sum * jax.lax.rsqrt(jnp.maximum(counts, 1.0))
+
     qi = params.q[i]
     r_u = effective_ranks(pu, t_p)
     r_i = effective_ranks(qi, t_q)
@@ -287,27 +412,23 @@ def _train_step(
         new_params = new_params._replace(user_bias=new_bu, item_bias=new_bi)
         new_state = new_state._replace(user_bias=st_bu, item_bias=st_bi)
 
-    if params.implicit is not None and hist is not None:
+    if slots is not None:
         # dL/dy_j = lam*y_j - err * q_i / sqrt(|N(u)|) for each j in N(u),
-        # masked by the rating's mask; padding slots add nothing
-        n_items = params.implicit.shape[0] - 1
-        counts = jnp.sum((hist < n_items).astype(jnp.float32), axis=1, keepdims=True)
+        # masked by the rating's mask; dead slots add nothing
         coef = err[:, None] * jax.lax.rsqrt(jnp.maximum(counts, 1.0))
-        g_y = lam * params.implicit[hist] - (coef * qi)[:, None, :]
-        flat_idx = hist.reshape(-1)
-        flat_mask = jnp.repeat(mask, hist.shape[1], axis=0) * (
-            flat_idx < n_items
-        ).astype(jnp.float32)[:, None]
+        y_idx = slots.item
+        g_y = lam * y_rows - (coef * qi)[slots.rating]
+        y_mask = mask[slots.rating] * slots.live.astype(jnp.float32)[:, None]
         new_y, st_y = opt.apply_rows(
-            params.implicit, opt_state.implicit, flat_idx,
-            g_y.reshape(-1, k).astype(jnp.float32), flat_mask, lr,
+            params.implicit, opt_state.implicit, y_idx,
+            g_y.astype(jnp.float32), y_mask, lr,
         )
         new_params = new_params._replace(implicit=new_y)
         new_state = new_state._replace(implicit=st_y)
-        flat_live = jnp.any(flat_mask != 0, axis=-1)
+        y_live = jnp.any(y_mask != 0, axis=-1)
         rows = rows + (
-            rows_written(flat_idx, flat_live, n_items + 1)
-            / jnp.maximum(jnp.sum(flat_live), 1),
+            rows_written(y_idx, y_live, n_items + 1)
+            / jnp.maximum(jnp.sum(y_live), 1),
         )
 
     return new_params, new_state, _step_metrics(err, w, pair_ranks, k, rows)
@@ -327,7 +448,7 @@ def _step_metrics(err, w, pair_ranks, k, rows) -> Dict[str, jax.Array]:
     }
 
 
-train_step = jax.jit(_train_step, static_argnames=("opt", "lam"))
+train_step = jax.jit(_train_step, static_argnames=("opt", "lam", "capacity"))
 
 
 def _eval_mae(
@@ -386,7 +507,7 @@ def _epoch_scan(step_fn, params, opt_state, batches):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("opt", "lam"),
+    static_argnames=("opt", "lam", "capacity"),
     donate_argnums=(0, 1),
 )
 def train_epoch_scan(
@@ -401,6 +522,7 @@ def train_epoch_scan(
     *,
     opt: RowOptimizer,
     lam: float,
+    capacity: Optional[int] = None,   # SVD++: mf.implicit_capacity of hist
 ) -> Tuple[MFParams, MFOptState, Dict[str, jax.Array]]:
     """A whole epoch as ONE compiled, donated computation.
 
@@ -418,7 +540,8 @@ def train_epoch_scan(
         if hist is not None:
             batch = dict(batch, hist=hist[batch["user"]])
         return _train_step(
-            p, s, batch, t_p, t_q, lr, dim_mask, opt=opt, lam=lam
+            p, s, batch, t_p, t_q, lr, dim_mask, opt=opt, lam=lam,
+            capacity=capacity,
         )
 
     return _epoch_scan(step, params, opt_state, batches)

@@ -109,6 +109,9 @@ class EpochRecord:
     user_rows_share: float = float("nan")
     item_rows_share: float = float("nan")
     implicit_rows_share: float = float("nan")
+    # SVD++: share of the epoch's steps whose implicit side ran on the live
+    # history slots packed to the trainer's capacity (mf.implicit_capacity)
+    implicit_compact_share: float = float("nan")
 
 
 class DPMFTrainer:
@@ -190,13 +193,23 @@ class DPMFTrainer:
             elif train_ds is None:
                 raise ValueError("either train_ds or config.store_dir is required")
             self.hist = None
+            self._implicit_capacity = None
             if config.variant == "svdpp":
                 with tracing.span("repro.trainer.history") as sp:
                     self.hist, truncated = build_user_history(train_ds, config.max_hist)
+                    sizes = np.sum(self.hist < train_ds.num_items, axis=1)
+                    # the history slots of a step that Y's side runs on,
+                    # from |N(u)| of every training rating's user
+                    self._implicit_capacity = mf.implicit_capacity(
+                        sizes[train_ds.user],
+                        min(config.batch_size, max(len(train_ds), 1)),
+                        config.max_hist,
+                    )
                     sp.set_metadata(
                         users=train_ds.num_users,
-                        slots=int(np.sum(self.hist < train_ds.num_items)),
+                        slots=int(np.sum(sizes)),
                         truncated=truncated,
+                        capacity=self._implicit_capacity,
                     )
             # Upload the ratings (and eval set / SVD++ history) ONCE;
             # per-epoch reshuffles happen on device (data/loader.py).  The
@@ -501,6 +514,7 @@ class DPMFTrainer:
                         self._hist_dev,
                         opt=self.opt,
                         lam=cfg.lam,
+                        capacity=self._implicit_capacity,
                     )
                 with tracing.span("repro.trainer.sync"):
                     jax.block_until_ready(self.params.p)
@@ -510,7 +524,8 @@ class DPMFTrainer:
             work = float(epoch_metrics["work_fraction"])
             rows_shares = {
                 key: float(epoch_metrics[key])
-                for key in ("user_rows_share", "item_rows_share", "implicit_rows_share")
+                for key in ("user_rows_share", "item_rows_share",
+                            "implicit_rows_share", "implicit_compact_share")
                 if key in epoch_metrics
             }
             wall = time.perf_counter() - start

@@ -159,6 +159,14 @@ class OnlineUpdater:
             None if user_history is None
             else np.array(user_history, np.int32, copy=True)
         )
+        # |N(u)| of each user as built: the history capacity of a chunk
+        # size (mf.implicit_capacity) is fixed from these, so a chunk size
+        # compiles one step however the histories fill
+        self._history_sizes = (
+            None if user_history is None
+            else np.sum(self.user_history < params.q.shape[0], axis=1)
+        )
+        self._capacities: Dict[int, int] = {}
         self._dim_mask = jnp.ones((params.p.shape[1],), jnp.float32)
         self.evictor = None  # store.eviction.UserEvictor via attach_evictor
 
@@ -467,12 +475,14 @@ class OnlineUpdater:
                 if weights is not None:
                     step_batch["weight"] = jnp.asarray(weights[lo : lo + size])
                 lo += size
+                capacity = None
                 if self.user_history is not None:
                     step_batch["hist"] = jnp.asarray(self.user_history[u])
+                    capacity = self._implicit_capacity(size)
                 self.params, self.opt_state, metrics = mf.train_step(
                     self.params, self.opt_state, step_batch,
                     self.t_p, self.t_q, self.lr, self._dim_mask,
-                    opt=self.opt, lam=self.lam,
+                    opt=self.opt, lam=self.lam, capacity=capacity,
                 )
                 abs_err += float(metrics["abs_err"]) * size
                 work += float(metrics["work_fraction"]) * size
@@ -494,6 +504,16 @@ class OnlineUpdater:
             "work_fraction": work / total,
             "events": total,
         }
+
+    def _implicit_capacity(self, size: int) -> int:
+        """The history slots a chunk of ``size`` events runs Y's side on,
+        from the users' |N(u)| when the updater was built; a step whose
+        histories have filled past it trains on all of its slots."""
+        if size not in self._capacities:
+            self._capacities[size] = mf.implicit_capacity(
+                self._history_sizes, size, self.user_history.shape[1]
+            )
+        return self._capacities[size]
 
     # -- threshold drift maintenance -----------------------------------------
     def _candidate_thresholds(self):

@@ -178,6 +178,24 @@ def test_svdpp_apply_appends_history_and_touches_implicit():
     assert set(live) <= set(snap.touched_implicit_items.tolist())
 
 
+def test_svdpp_apply_compiles_one_step_per_chunk_size():
+    """The updater fixes each chunk size's history capacity when it is
+    built, so applies whose histories fill compile no more step programs
+    than there are chunk sizes (32 and 16 here)."""
+    ds = synthetic_ratings(40, 60, 200, seed=1)
+    hist, _ = build_user_history(ds, 16)
+    upd = OnlineUpdater(_params(40, 60, 8, variant="svdpp"), None, 0.0, 0.0,
+                        user_history=hist, batch_size=32)
+    live_before = int(np.sum(upd.user_history < 60))
+    rng = np.random.default_rng(2)
+    compiled = mf.train_step._cache_size()
+    for _ in range(6):
+        upd.apply(_batch(rng.integers(0, 40, 48), rng.integers(0, 60, 48),
+                         rng.uniform(1, 5, 48)))
+    assert int(np.sum(upd.user_history < 60)) > live_before + 200
+    assert mf.train_step._cache_size() - compiled <= 2
+
+
 def test_svdpp_requires_history():
     params = _params(variant="svdpp")
     with pytest.raises(ValueError, match="user_history"):

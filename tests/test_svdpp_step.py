@@ -11,11 +11,16 @@ no pruning (thresholds 0), so each update is -lr times the gradient:
     y_j += lr (e |N(u)|^-1/2 q_i - lam y_j)   for j in N(u)
     b_u += lr (e - lam b_u),  b_i += lr (e - lam b_i)
 """
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import mf
-from repro.optim.optimizers import RowOptimizer
+from repro.data import build_user_history, synthetic_ratings
+from repro.optim.optimizers import DOUBLING_MAX, RowOptimizer
 
 LR, LAM, MU = 0.1, 0.02, 3.0
 P = np.array([[0.1, 0.2], [0.3, -0.1]])
@@ -64,3 +69,181 @@ def test_svdpp_step_is_korens_update():
     # p_0) per rating, 2.8e-4 here: far outside that tolerance
     z0 = P[0] + (Y[0] + Y[1]) / np.sqrt(2)
     assert np.abs(2 * LR * LAM * (z0 - P[0])).max() > 100 * 1e-6
+
+
+# ---------------------------------------------------------------------------
+# the implicit side on the step's live history slots, packed to a capacity
+# ---------------------------------------------------------------------------
+
+K = 8
+
+
+def _svdpp_problem(num_users, num_items, max_hist, seed=0, optimizer="adagrad"):
+    """Ratings, their SVD++ histories, a model and its optimizer state."""
+    ds = synthetic_ratings(num_users, num_items, 7 * num_users, seed=seed)
+    hist, _ = build_user_history(ds, max_hist)
+    opt = RowOptimizer(name=optimizer)
+    params = mf.init_params(jax.random.PRNGKey(seed), num_users, num_items, K,
+                            variant="svdpp", global_mean=3.0)
+    return ds, hist, params, mf.init_opt_state(params, opt), opt
+
+
+def _in_place_slots(hist, num_items):
+    """Every one of the (B, H) ``hist``'s slots where it stands, padding
+    included: the full-width layout that the packing takes the padding out
+    of (a padding slot reads Y's zero row and is masked out)."""
+    b, h = hist.shape
+    flat = hist.reshape(-1)
+    pos = jnp.arange(b * h)
+    return mf._Slots(item=flat, rating=pos // h, head=pos % h == 0,
+                     live=flat < num_items, last=jnp.arange(b) * h + h - 1,
+                     count=jnp.sum(flat < num_items))
+
+
+@functools.partial(jax.jit, static_argnames=("opt", "lam", "capacity"))
+def _full_width_step(params, state, batch, t_p, t_q, lr, dim_mask, *, opt, lam,
+                     capacity):
+    """The step over all B*H slots in place, with no packing."""
+    del capacity
+    slots = _in_place_slots(batch["hist"], params.implicit.shape[0] - 1)
+    return mf._masked_step(params, state, batch, t_p, t_q, lr, dim_mask, slots,
+                           opt=opt, lam=lam)
+
+
+def _steps(params, state, opt, ds, hist, batch, capacity, steps=3, weight=False,
+           step=mf.train_step):
+    """``steps`` pruned Adagrad steps over the first batches of ``ds``."""
+    metrics = []
+    rng = np.random.default_rng(1)
+    for s in range(steps):
+        sl = slice(s * batch, (s + 1) * batch)
+        users = ds.user[sl]
+        b = {"user": jnp.asarray(users), "item": jnp.asarray(ds.item[sl]),
+             "rating": jnp.asarray(ds.rating[sl]), "hist": jnp.asarray(hist[users])}
+        if weight:
+            b["weight"] = jnp.asarray(rng.uniform(0, 1, batch).astype(np.float32))
+        params, state, m = step(
+            params, state, b, jnp.float32(0.04), jnp.float32(0.04),
+            jnp.float32(0.05), jnp.ones((K,), jnp.float32), opt=opt, lam=0.02,
+            capacity=capacity,
+        )
+        metrics.append({key: float(v) for key, v in m.items()})
+    return params, state, metrics
+
+
+def _assert_states_close(got, want, exact=False):
+    # float32 sums of up to a few thousand terms in another order: a few
+    # hundred ulp of the largest entry
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        if exact:
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        else:
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-5, atol=1e-6)
+
+
+def _live_slots(hist, users, num_items):
+    return int(np.sum(hist[users] < num_items))
+
+
+@pytest.mark.parametrize(
+    "num_users,max_hist,batch",
+    [(300, 32, 128),      # 1,024 packed slots: the run sums double
+     (2000, 32, 4096)],   # 32,768 packed slots, as at the BookCrossings
+                          # cell: past DOUBLING_MAX, a segmented scan
+    ids=["doubling", "segmented_scan"],
+)
+def test_packed_step_matches_full_width_step(num_users, max_hist, batch):
+    """P, Q, Y, the biases, every Adagrad table and the step metrics of
+    steps whose implicit side runs on the packed live slots match the
+    steps packed to all B*H slots, whose sums run in the same order."""
+    ds, hist, params, state, opt = _svdpp_problem(num_users, 2 * num_users, max_hist)
+    sizes = np.sum(hist < ds.num_items, axis=1)[ds.user]
+    capacity = mf.implicit_capacity(sizes, batch, max_hist)
+    assert capacity <= batch * max_hist // 4
+    assert DOUBLING_MAX < capacity or batch * max_hist <= DOUBLING_MAX
+    full = _steps(params, state, opt, ds, hist, batch, None)
+    packed = _steps(params, state, opt, ds, hist, batch, capacity)
+    _assert_states_close(packed[:2], full[:2])
+    for got, want in zip(packed[2], full[2]):
+        assert got.pop("implicit_compact_share") == 1.0
+        assert want.pop("implicit_compact_share") == 0.0
+        assert got == pytest.approx(want, rel=1e-5)
+
+
+def _scatter_rows(hist, seed=3):
+    """``hist`` with each row's slots shuffled, as FIFO eviction may leave
+    them: live slots need not lead their rows."""
+    rng = np.random.default_rng(seed)
+    return np.take_along_axis(hist, rng.permuted(
+        np.broadcast_to(np.arange(hist.shape[1]), hist.shape), axis=1), axis=1)
+
+
+@pytest.mark.parametrize(
+    "num_users,batch,scatter",
+    [(300, 128, False), (2000, 4096, False), (300, 128, True)],
+    ids=["doubling", "segmented_scan", "scattered"],
+)
+def test_packed_step_matches_slots_in_place(num_users, batch, scatter):
+    """Under plain SGD, whose update is linear in each gradient, steps on
+    the packed live slots match steps over every slot where it stands in
+    ``hist``, padding included: a layout that needs no packing.  (Adagrad
+    divides by the root of its accumulator, which turns the last-ulp
+    differences of a hot row's nearly cancelling gradient sum, added in
+    another order, into differences of 1e-5.)"""
+    ds, hist, params, state, opt = _svdpp_problem(num_users, 2 * num_users, 32,
+                                                  optimizer="sgd")
+    if scatter:
+        hist = _scatter_rows(hist)
+    sizes = np.sum(hist < ds.num_items, axis=1)[ds.user]
+    capacity = mf.implicit_capacity(sizes, batch, 32)
+    in_place = _steps(params, state, opt, ds, hist, batch, None, weight=scatter,
+                      step=_full_width_step)
+    packed = _steps(params, state, opt, ds, hist, batch, capacity, weight=scatter)
+    _assert_states_close(packed[:2], in_place[:2])
+    for got, want in zip(packed[2], in_place[2]):
+        assert got.pop("implicit_compact_share") == 1.0
+        assert got == pytest.approx(want, rel=1e-5)
+
+
+def test_packed_step_takes_live_slots_anywhere_in_a_row():
+    """Live slots need not lead their rows (FIFO eviction leaves them
+    anywhere): the packed step still matches the step packed to all B*H
+    slots, with a weighted batch."""
+    ds, hist, params, state, opt = _svdpp_problem(300, 600, 32)
+    scattered = _scatter_rows(hist)
+    assert (scattered[:, 0] == ds.num_items).any()
+    assert (np.sort(scattered, axis=1) == hist).all()
+    sizes = np.sum(hist < ds.num_items, axis=1)[ds.user]
+    capacity = mf.implicit_capacity(sizes, 128, 32)
+    full = _steps(params, state, opt, ds, scattered, 128, None, weight=True)
+    packed = _steps(params, state, opt, ds, scattered, 128, capacity, weight=True)
+    _assert_states_close(packed[:2], full[:2])
+    assert [m["implicit_compact_share"] for m in packed[2]] == [1.0] * 3
+
+
+def test_step_past_its_capacity_trains_on_every_slot():
+    """A batch with more live slots than the capacity takes the branch
+    packed to all B*H slots: the same tables as the step with no capacity,
+    and a step counted 0 in ``implicit_compact_share``."""
+    ds, hist, params, state, opt = _svdpp_problem(300, 600, 32)
+    capacity = 128 * 32 // 8
+    assert all(_live_slots(hist, ds.user[s * 128:(s + 1) * 128], ds.num_items)
+               > capacity for s in range(3))
+    full = _steps(params, state, opt, ds, hist, 128, None)
+    over = _steps(params, state, opt, ds, hist, 128, capacity)
+    _assert_states_close(over[:2], full[:2], exact=True)
+    assert [m["implicit_compact_share"] for m in over[2]] == [0.0] * 3
+    assert [m["abs_err"] for m in over[2]] == [m["abs_err"] for m in full[2]]
+
+
+def test_implicit_capacity_rule():
+    """The smallest B*H / 2^j that holds B * mean + 6 sqrt(B) * std live
+    slots: 32,768 of 131,072 at the BookCrossings cell's N(u) (mean 7.37,
+    std 2.26, rating-weighted), all of them at full histories."""
+    sizes = np.array([7.37 - 2.26, 7.37 + 2.26])
+    assert mf.implicit_capacity(sizes, 4096, 32) == 32_768
+    assert mf.implicit_capacity(np.full(10, 32), 4096, 32) == 4096 * 32
+    # just past half of the slots keeps all of them
+    assert mf.implicit_capacity(np.full(10, 17), 4096, 32) == 4096 * 32
+    assert mf.implicit_capacity(np.full(10, 16), 4096, 32) == 4096 * 16

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -160,12 +161,11 @@ def test_train_epoch_scan_compiles(one_chip, monkeypatch):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
-def test_train_epoch_scan_svdpp_compiles(one_chip, monkeypatch):
+def _svdpp_epoch_program(one_chip, monkeypatch, **kw):
     """The SVD++ epoch program of ``bookx_svdpp_k128.svdpp_epochs``: k 128,
-    histories of 32 slots, batches of 4,096, a 340,555-row implicit table
-    whose row write takes 131,072 ids a step (512 KiB of ids in SMEM,
-    half of it).  ``write_rows`` picks the kernel by backend, which is the
-    CPU here: the test steers it to the chip's branch."""
+    histories of 32 slots, batches of 4,096, a 340,555-row implicit table.
+    ``write_rows`` picks the kernel by backend, which is the CPU here: the
+    steering to the chip's branch is the test's."""
     m, n, hist_len, steps, batch = 105_284, 340_554, 32, 8, 4096
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     opt = RowOptimizer(name="adagrad")
@@ -182,14 +182,61 @@ def test_train_epoch_scan_svdpp_compiles(one_chip, monkeypatch):
         "rating": _sds((steps, batch), jnp.float32, one_chip),
     }
     scalar = _sds((), jnp.float32, one_chip)
-    compiled = mf.train_epoch_scan.lower(
+    return mf.train_epoch_scan.lower(
         _abstract(params, place), _abstract(state, place), batches,
         scalar, scalar, scalar, _sds((K,), jnp.float32, one_chip),
         _sds((m, hist_len), jnp.int32, one_chip),
-        opt=opt, lam=0.02,
+        opt=opt, lam=0.02, **kw,
     ).compile()
+
+
+def _table_copies(text: str) -> int:
+    """Copies of P, Q or Y (or their Adagrad tables, of the same shapes) in
+    a compiled SVD++ program's text: each would move a whole table a
+    step."""
+    shapes = ("f32[105284,128]", "f32[340554,128]", "f32[340555,128]")
+    results = (re.search(r"= (.*?) copy(?:-start)?\(", line)
+               for line in text.splitlines())
+    return sum(1 for m in results if m and any(s in m.group(1) for s in shapes))
+
+
+def _branches(text: str):
+    """The texts of the computations of a compiled program's one
+    ``conditional``, in branch order (a ``lax.cond``'s false branch
+    first)."""
+    lines = text.splitlines()
+    names = re.search(r"branch_computations=\{%([^,}]+), %([^,}]+)\}", text).groups()
+    out = []
+    for name in names:
+        start = next(i for i, line in enumerate(lines) if line.startswith(f"%{name} "))
+        out.append("\n".join(lines[start:lines.index("}", start)]))
+    return out
+
+
+def test_train_epoch_scan_svdpp_compiles(one_chip, monkeypatch):
+    """The SVD++ epoch program over all of a step's history slots, whose
+    row write of Y takes 131,072 ids a step (512 KiB of ids in SMEM, half
+    of it)."""
+    compiled = _svdpp_epoch_program(one_chip, monkeypatch)
     # the row-DMA kernel writes P, Q and Y; the bias columns go by scatter
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 3
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_train_epoch_scan_svdpp_packed_compiles(one_chip, monkeypatch):
+    """The cell's own SVD++ epoch program: Y's side on the live slots
+    packed to the trainer's capacity of 32,768, and the step over all
+    131,072 slots in the other branch of the step's ``lax.cond``."""
+    compiled = _svdpp_epoch_program(one_chip, monkeypatch, capacity=32_768)
+    text = compiled.as_text()
+    # P, Q and Y written by the row-DMA kernel in each branch
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    # the packed branch, which the cell runs, updates the scan's carried
+    # tables in place: no copy of a table around it
+    every_slot, packed = _branches(text)
+    assert "f32[131072,128]" in every_slot and "f32[131072,128]" not in packed
+    assert _table_copies(packed) == 0
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 

@@ -153,7 +153,8 @@ def _svdpp_trainer():
 def test_history_span_counts_users_slots_and_cut(tmp_path):
     """``repro.trainer.history`` wraps the SVD++ history build inside
     ``repro.trainer.init``, with the users, the live slots (distinct
-    training pairs kept) and the users cut at ``max_hist``."""
+    training pairs kept), the users cut at ``max_hist`` and the capacity
+    of history slots a step runs on (all 64 x 8: the histories are full)."""
     trainer, spans = _traced(tmp_path, _svdpp_trainer)
     (hist,) = [sp for sp in spans if sp[2] == "repro.trainer.history"]
     assert _parent(spans, hist) == "repro.trainer.init"
@@ -163,8 +164,29 @@ def test_history_span_counts_users_slots_and_cut(tmp_path):
         "users": 30,
         "slots": sum(min(len(s), 8) for s in sets),
         "truncated": sum(len(s) > 8 for s in sets),
+        "capacity": 64 * 8,
     }
     assert hist[3]["truncated"] > 0   # 16 training ratings a user: some cut
+
+
+def test_svdpp_epochs_record_packed_steps(tmp_path):
+    """With sparse histories (about 5 of 32 slots live) the trainer's steps
+    run Y's side on the live slots packed to its capacity: the history span
+    carries the capacity, 2,048 of a step's 256 x 32 slots, and every
+    epoch records ``implicit_compact_share`` 1; the funk trainer has none."""
+    ds = synthetic_ratings(400, 600, 2400, seed=4)
+    train, test = train_test_split(ds, 0.2, seed=4)
+    config = TrainConfig(k=8, epochs=2, batch_size=256, pruning_rate=0.3,
+                         seed=5, variant="svdpp", max_hist=32)
+    trainer, spans = _traced(tmp_path, lambda: DPMFTrainer(config, train, test))
+    (hist,) = [sp for sp in spans if sp[2] == "repro.trainer.history"]
+    sizes = [len(set(train.item[train.user == u].tolist())) for u in train.user]
+    need = 256 * np.mean(sizes) + 6 * 16 * np.std(sizes)
+    assert 1024 < need <= 2048
+    assert hist[3]["capacity"] == 2048
+    records = trainer.run()
+    assert [r.implicit_compact_share for r in records] == [1.0, 1.0]
+    assert np.isnan(_trainer().run_epoch().implicit_compact_share)
 
 
 def test_implicit_rows_share_counts_distinct_rows_of_y():
