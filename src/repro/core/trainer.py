@@ -23,8 +23,9 @@ import numpy as np
 
 from repro import checkpoint as ckpt_lib
 from repro import tracing
-from repro.core import mf, rearrange, threshold
+from repro.core import als, mf, rearrange, threshold
 from repro.data import loader
+from repro.data import pairs as pairs_lib
 from repro.data.ratings import RatingsDataset, build_user_history
 from repro.distributed.fault_tolerance import (
     StragglerDetector,
@@ -55,7 +56,11 @@ class TrainConfig:
     #           column riding train_step's batch["weight"] gate
     # bpr:      pairwise -log σ(s_ui - s_uj) on per-epoch sampled triples
     #           (test_mae is NaN, ranking metrics carry)
-    objective: str = "explicit"        # explicit | implicit | bpr
+    # ials:     the same loss over every user x item pair, solved by
+    #           alternating least squares (core/als.py): an epoch is a user
+    #           half-step and an item half-step over the log's merged pairs,
+    #           lam scaled by each row's pairs; no optimizer state
+    objective: str = "explicit"        # explicit | implicit | bpr | ials
     implicit_alpha: float = 40.0       # confidence c = 1 + alpha·r
     implicit_negatives: int = 4        # sampled unobserved items / positive
     seed: int = 0
@@ -93,7 +98,7 @@ class EpochRecord:
 
     epoch: int
     wall_time_s: float
-    train_abs_err: float
+    train_abs_err: float   # ials: the loss over every pair, per observed pair
     test_mae: float
     work_fraction: float   # mean k_eff / k — the work-proportional cost
     t_p: float
@@ -112,6 +117,10 @@ class EpochRecord:
     # SVD++: share of the epoch's steps whose implicit side ran on the live
     # history slots packed to the trainer's capacity (mf.implicit_capacity)
     implicit_compact_share: float = float("nan")
+    # iALS: share of the pair slots of both layouts that are padding, and the
+    # rows of both sides whose pairs span more than one chunk (data/pairs.py)
+    als_pad_share: float = float("nan")
+    als_split_rows: float = float("nan")
 
 
 class DPMFTrainer:
@@ -126,8 +135,14 @@ class DPMFTrainer:
         with tracing.span("repro.trainer.init"):
             self.config = config
             self.opt = RowOptimizer(name=config.optimizer)
-            if config.objective not in ("explicit", "implicit", "bpr"):
+            if config.objective not in ("explicit", "implicit", "bpr", "ials"):
                 raise ValueError(f"unknown objective {config.objective!r}")
+            if config.objective == "ials" and (
+                config.variant != "funk" or config.strategy != "standard"
+            ):
+                raise ValueError(
+                    "ials solves FunkSVD tables with the standard strategy"
+                )
             self._train_weight = None      # implicit confidence column
             self._bpr_sampler = None
             if config.objective != "explicit":
@@ -145,17 +160,18 @@ class DPMFTrainer:
                     raise ValueError(
                         f"objective {config.objective!r} requires train_ds"
                     )
-            if config.objective == "implicit":
+            if config.objective in ("implicit", "ials"):
                 from repro.workloads import implicit as implicit_wl
 
-                # one-time expansion: positives + sampled negatives, with the
-                # WALS confidence column carried as per-example weights
-                train_ds, self._train_weight = implicit_wl.implicit_dataset(
-                    train_ds,
-                    alpha=config.implicit_alpha,
-                    negatives=config.implicit_negatives,
-                    seed=config.seed,
-                )
+                if config.objective == "implicit":
+                    # one-time expansion: positives + sampled negatives, with
+                    # the WALS confidence column carried as per-example weights
+                    train_ds, self._train_weight = implicit_wl.implicit_dataset(
+                        train_ds,
+                        alpha=config.implicit_alpha,
+                        negatives=config.implicit_negatives,
+                        seed=config.seed,
+                    )
                 if test_ds is not None:
                     # held-out interactions as preference-1 targets: test MAE
                     # reads "distance from 1 on the user's actual items"
@@ -194,6 +210,15 @@ class DPMFTrainer:
                 raise ValueError("either train_ds or config.store_dir is required")
             self.hist = None
             self._implicit_capacity = None
+            # iALS: the log merged into pairs, laid out by user and by item
+            self._pairs = (
+                pairs_lib.pair_layouts(
+                    train_ds.user, train_ds.item, train_ds.rating,
+                    train_ds.num_users, train_ds.num_items,
+                    alpha=config.implicit_alpha,
+                )
+                if config.objective == "ials" else None
+            )
             if config.variant == "svdpp":
                 with tracing.span("repro.trainer.history") as sp:
                     self.hist, truncated = build_user_history(train_ds, config.max_hist)
@@ -223,7 +248,8 @@ class DPMFTrainer:
                     min(config.batch_size, max(len(train_ds), 1)),
                     weight=self._train_weight,
                 )
-                if self._loader is None and config.objective != "bpr"
+                if self._loader is None
+                and config.objective not in ("bpr", "ials")
                 else None
             )
             if config.objective == "bpr":
@@ -257,7 +283,10 @@ class DPMFTrainer:
                 init_method=config.init_method,
                 global_mean=src.global_mean,
             )
-            self.opt_state = mf.init_opt_state(self.params, self.opt)
+            self.opt_state = (
+                None if config.objective == "ials"
+                else mf.init_opt_state(self.params, self.opt)
+            )
             self.t_p = jnp.float32(0.0)
             self.t_q = jnp.float32(0.0)
             self.perm: Optional[jax.Array] = None
@@ -365,6 +394,9 @@ class DPMFTrainer:
                 self.params = self.params._replace(
                     implicit=jnp.take(self.params.implicit, self.perm, axis=1)
                 )
+            if self.opt_state is None:   # ials keeps no optimizer state
+                return
+
             # Keep optimizer accumulators aligned with the permuted latent axis.
             def permute_state(state):
                 return {
@@ -476,6 +508,8 @@ class DPMFTrainer:
                     "abs_err": err_sum / max(steps_done, 1),
                     "work_fraction": work_sum / max(steps_done, 1),
                 }
+            elif cfg.objective == "ials":
+                epoch_metrics = self._als_epoch(t_p, t_q)
             elif cfg.objective == "bpr":
                 # Pairwise epoch: freshly sampled (user, pos, neg) triples folded
                 # through the same scan machinery; abs_err carries the BPR loss.
@@ -525,7 +559,8 @@ class DPMFTrainer:
             rows_shares = {
                 key: float(epoch_metrics[key])
                 for key in ("user_rows_share", "item_rows_share",
-                            "implicit_rows_share", "implicit_compact_share")
+                            "implicit_rows_share", "implicit_compact_share",
+                            "als_pad_share", "als_split_rows")
                 if key in epoch_metrics
             }
             wall = time.perf_counter() - start
@@ -565,6 +600,32 @@ class DPMFTrainer:
             ):
                 self.save(self._ckpt_step())
             return record
+
+    def _als_epoch(self, t_p, t_q) -> Dict[str, Any]:
+        """One iALS epoch (``core/als.py``): the user half-step, then the
+        item half-step, each under a ``repro.trainer.als`` span; then the
+        epoch's one sync.  ``abs_err`` carries the loss per observed pair
+        and ``work_fraction`` the mean solved rank over k."""
+        cfg, layouts = self.config, self._pairs
+        p, q = self.params.p, self.params.q
+        user, item = layouts.user, layouts.item
+        with tracing.span("repro.trainer.als", side="user",
+                          rows=user.solved_rows, chunks=user.chunks):
+            p, ranks_u = als.half_step(p, q, t_p, t_q, user, lam=cfg.lam)
+        with tracing.span("repro.trainer.als", side="item",
+                          rows=item.solved_rows, chunks=item.chunks):
+            q, ranks_i = als.half_step(q, p, t_q, t_p, item, lam=cfg.lam)
+        self.params = self.params._replace(p=p, q=q)
+        with tracing.span("repro.trainer.sync"):
+            loss = als.objective(p, q, t_p, t_q, layouts, lam=cfg.lam)
+            loss, rank_sum = jax.device_get((loss, ranks_u + ranks_i))
+        solved = user.solved_rows + item.solved_rows
+        return {
+            "abs_err": float(loss) / layouts.pairs,
+            "work_fraction": float(rank_sum) / (max(solved, 1) * cfg.k),
+            "als_pad_share": layouts.pad_share,
+            "als_split_rows": layouts.split_rows,
+        }
 
     def run(self) -> List[EpochRecord]:
         start_epoch = self.epoch

@@ -45,11 +45,13 @@ def main() -> None:
     parser.add_argument("--variant", default="funk",
                         choices=["funk", "bias", "svdpp"])
     parser.add_argument("--objective", default="explicit",
-                        choices=["explicit", "implicit", "bpr"],
+                        choices=["explicit", "implicit", "bpr", "ials"],
                         help="explicit: squared rating error (the paper); "
                              "implicit: WALS confidence-weighted binary "
                              "preference with sampled negatives; bpr: "
-                             "pairwise ranking loss (test mae is NaN)")
+                             "pairwise ranking loss (test mae is NaN); "
+                             "ials: the implicit loss over every pair, "
+                             "solved by alternating least squares")
     parser.add_argument("--implicit-alpha", type=float, default=40.0,
                         help="implicit confidence c = 1 + alpha*r")
     parser.add_argument("--implicit-negatives", type=int, default=4,

@@ -31,9 +31,10 @@ from repro.data.ratings import RatingsDataset
 from repro.online.stream import Event, EventBatch, iter_microbatches
 
 
-def confidence_weights(ratings: np.ndarray, alpha: float) -> np.ndarray:
-    """WALS confidence ``c = 1 + alpha·r`` for interaction strengths ``r``."""
-    return (1.0 + alpha * np.asarray(ratings, np.float32)).astype(np.float32)
+def confidence_weights(ratings, alpha: float):
+    """WALS confidence ``c = 1 + alpha·r`` for interaction strengths ``r``,
+    float32, of the kind of ``ratings`` (a numpy or a device array)."""
+    return 1.0 + alpha * ratings.astype(np.float32)
 
 
 def _positive_sets(user: np.ndarray, item: np.ndarray, num_users: int):

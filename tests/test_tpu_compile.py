@@ -241,6 +241,37 @@ def test_train_epoch_scan_svdpp_packed_compiles(one_chip, monkeypatch):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
+def test_als_half_step_programs_compile(one_chip):
+    """The implicit-ALS programs of ``ml25m_ials_k128.ials_epochs`` at its
+    shapes (162,541 users, 62,423 items, about 184k item chunks of 64
+    pairs, blocks of 2,048 rows, 256 chunks a launch): the Gramian
+    accumulation, and the solve on the chip's own batched Cholesky, each
+    within the chip's memory."""
+    from repro.core import als
+    from repro.data import pairs
+
+    m, n, c = 162_541, 62_423, pairs.CHUNK
+    rows, launch = pairs.ROWS_PER_BLOCK, pairs.CHUNKS_PER_LAUNCH
+    chunks = 184_000 + launch
+    f32, i32 = jnp.float32, jnp.int32
+    scalar = lambda dt: _sds((), dt, one_chip)  # noqa: E731
+    gram = als.als_gram.lower(
+        _sds((m, K), f32, one_chip), _sds((chunks,), i32, one_chip),
+        _sds((chunks, c), i32, one_chip), _sds((chunks, c), f32, one_chip),
+        scalar(i32), scalar(i32), scalar(i32), rows=rows, launch=launch,
+    ).compile()
+    solve = als.als_solve.lower(
+        _sds((m, K), f32, one_chip), _sds((rows, K, K), f32, one_chip),
+        _sds((rows, K), f32, one_chip), _sds((K, K), f32, one_chip),
+        _sds((m,), f32, one_chip), scalar(f32), scalar(i32), scalar(i32),
+        scalar(i32), scalar(f32),
+    ).compile()
+    assert 'custom_call_target="Cholesky"' in solve.as_text()
+    for compiled in (gram, solve):
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
 @pytest.mark.parametrize(
     "shape,names", [((4,), ("model",)), ((2, 2), ("data", "model"))],
     ids=["model4", "data2xmodel2"],

@@ -204,3 +204,46 @@ def test_implicit_rows_share_counts_distinct_rows_of_y():
         shares.append(np.unique(live).size / live.size)
     assert record.implicit_rows_share == pytest.approx(np.mean(shares), rel=1e-6)
     assert np.isnan(_trainer().run_epoch().implicit_rows_share)
+
+
+def _ials_trainer():
+    ds = synthetic_ratings(40, 30, 900, seed=3)
+    train, test = train_test_split(ds, 0.2, seed=3)
+    return DPMFTrainer(
+        TrainConfig(k=8, epochs=2, pruning_rate=0.3, seed=5, objective="ials", lam=0.1),
+        train, test)
+
+
+def test_ials_spans_and_counters(tmp_path):
+    """``repro.trainer.als`` wraps each half-step's dispatch inside
+    ``repro.trainer.epoch``, users then items, with the side, its rows that
+    have pairs and its chunks; the epoch records carry the layouts' padding
+    share and split rows, and the solved ranks' mean as the work fraction;
+    the funk trainer has no such counters."""
+    def trained():
+        trainer = _ials_trainer()
+        trainer.run()
+        return trainer
+
+    trained()   # compile outside the session
+    trainer, spans = _traced(tmp_path, trained)
+    als_spans = [sp for sp in spans if sp[2] == "repro.trainer.als"]
+    assert [sp[3]["side"] for sp in als_spans] == ["user", "item"] * 2
+    assert {_parent(spans, sp) for sp in als_spans} == {"repro.trainer.epoch"}
+    user, item = trainer._pairs.user, trainer._pairs.item
+    assert als_spans[0][3] == {"side": "user", "rows": user.solved_rows, "chunks": user.chunks}
+    assert als_spans[1][3] == {"side": "item", "rows": item.solved_rows, "chunks": item.chunks}
+    ds = trainer.train_ds
+    pairs = {(u, i) for u, i in zip(ds.user.tolist(), ds.item.tolist())}
+    counts_u = np.bincount([u for u, _ in pairs], minlength=ds.num_users)
+    counts_i = np.bincount([i for _, i in pairs], minlength=ds.num_items)
+    assert user.solved_rows == int(np.sum(counts_u > 0))
+    slots = (np.sum(-(-counts_u // user.chunk)) + np.sum(-(-counts_i // item.chunk))) * user.chunk
+    split = int(np.sum(counts_u > user.chunk) + np.sum(counts_i > item.chunk))
+    for record in trainer.history:
+        assert record.als_pad_share == pytest.approx(1 - 2 * len(pairs) / slots)
+        assert record.als_split_rows == split
+    assert trainer.history[0].work_fraction == 1.0
+    assert 0 < trainer.history[1].work_fraction < 1.0
+    funk = _trainer().run_epoch()
+    assert np.isnan(funk.als_pad_share) and np.isnan(funk.als_split_rows)
